@@ -133,6 +133,7 @@ from benchmarks.common import CSV
 from repro.core import kratos as kr
 from repro.distributed import steps as ST
 from repro.kernels import pallas_compat as PC
+from repro.launch import compile_cache
 from repro.serve import (DraftSpec, EngineConfig, InferenceEngine,
                          ModelRegistry, StaticScheduler, TelemetryConfig,
                          TelemetryExporter, TraceConfig, engine_sample)
@@ -858,8 +859,19 @@ def run_ledger_trace(arch: str, n_requests: int, n_slots: int, seed: int,
 
     # ungated roofline attribution: join the tracer's dispatch walls with
     # the ledger counter tracks drained at the same steps
-    dispatch_rows = RL.dispatch_rooflines(list(eng1.trace.events))
-    replica = RL.replica_roofline(sum1, wall1)
+    # (only against the device's published peaks — a CPU run has none)
+    kind = jax.devices()[0].device_kind
+    if kind in RL.PEAKS:
+        peaks = RL.peaks_for(kind)
+        dispatch_rows = RL.dispatch_rooflines(list(eng1.trace.events),
+                                              peaks=peaks)
+        replica = RL.replica_roofline(sum1, wall1, peaks=peaks)
+        bound = (f"skip bound {replica['skip_speedup_bound']:.2f}x "
+                 f"({replica['dense']['bound']}-bound)")
+    else:
+        dispatch_rows = []
+        replica = f"not measured: no published peaks for {kind!r}"
+        bound = "skip bound not measured"
 
     zero_frac = sum1["act_zeros"] / max(sum1["act_probe_elems"], 1.0)
     print(f"# ledger-trace[{arch}] kb={k_block}: "
@@ -876,9 +888,7 @@ def run_ledger_trace(arch: str, n_requests: int, n_slots: int, seed: int,
           f"{rep1['quality_logit_mad']:.3g} "
           f"[{'PASS' if quality_ok else 'FAIL'}] | eff flops "
           f"{rep1['effective_flop_fraction']:.3f}, dead k-blocks "
-          f"{int(sum1['act_dead_kblocks'])}, "
-          f"skip bound {replica['skip_speedup_bound']:.2f}x "
-          f"({replica['dense']['bound']}-bound)")
+          f"{int(sum1['act_dead_kblocks'])}, {bound}")
     records = [{
         "arch": arch, "mode": "ledger", "n_requests": n_requests,
         "n_slots": n_slots, "decode_chunk": 4, "k_block": k_block,
@@ -1270,6 +1280,7 @@ def main() -> None:
                          "tracer: JSONL + Chrome traces and one telemetry "
                          "snapshot per mode land here (CI artifacts)")
     a = ap.parse_args()
+    compile_cache.configure()
     if a.fleet:
         ok = run_fleet_trace(a.arch or "h2o-danube-1.8b",
                              a.requests or 12, a.slots, a.seed,

@@ -30,12 +30,11 @@ from typing import Any, Dict, Iterable, List, Optional
 
 @dataclasses.dataclass(frozen=True)
 class Peaks:
-    """Machine roof. Defaults are deliberately modest CPU-class numbers so
-    unit tests and laptop runs produce sane utilizations; real runs pass
-    measured peaks (e.g. from a dense GEMM sweep or the chip datasheet)."""
+    """Machine roof of one chip, with where its numbers come from."""
 
-    peak_flops: float = 2.0e11     # flop/s
-    peak_bw: float = 5.0e10        # bytes/s
+    peak_flops: float              # flop/s
+    peak_bw: float                 # HBM bytes/s
+    source: str = ""
 
     @property
     def ridge(self) -> float:
@@ -43,8 +42,27 @@ class Peaks:
         return self.peak_flops / self.peak_bw
 
 
+# Published per-chip peaks keyed by jax `Device.device_kind`. A device not
+# in the table is an error, never a default: a roofline share against
+# guessed peaks would read as a measurement.
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        peak_flops=197e12, peak_bw=819e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s per chip"),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The published roof of `device_kind`; KeyError if it has none."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
+
 def roofline_point(flops: float, bytes_: float, wall_s: float,
-                   peaks: Peaks = Peaks()) -> Dict[str, float]:
+                   peaks: Peaks) -> Dict[str, float]:
     """Classify one workload sample against the roof.
 
     Returns intensity (flop/byte), achieved flop/s and bytes/s, the roof
@@ -78,8 +96,8 @@ def _index_ledger(events: Iterable[Dict[str, Any]]) -> Dict[int, Dict[str, Any]]
 
 
 def dispatch_rooflines(events: Iterable[Dict[str, Any]],
-                       hlo_cost: Optional[Dict[str, Any]] = None,
-                       peaks: Peaks = Peaks()) -> List[Dict[str, Any]]:
+                       hlo_cost: Optional[Dict[str, Any]] = None, *,
+                       peaks: Peaks) -> List[Dict[str, Any]]:
     """Per-dispatch roofline rows from one tracer's event list.
 
     events: `Tracer.export()["events"]` (or the parsed JSONL) — the join
@@ -135,8 +153,8 @@ def dispatch_rooflines(events: Iterable[Dict[str, Any]],
 
 def replica_roofline(summary: Dict[str, Any], wall_s: float,
                      hlo_cost: Optional[Dict[str, Any]] = None,
-                     n_dispatches: int = 1,
-                     peaks: Peaks = Peaks()) -> Dict[str, Any]:
+                     n_dispatches: int = 1, *,
+                     peaks: Peaks) -> Dict[str, Any]:
     """Whole-replica roofline from `LedgerSink.summary()` totals.
 
     summary: drained cumulative totals (flops_dense / flops_effective /

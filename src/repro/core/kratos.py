@@ -257,6 +257,30 @@ def pack_linear(params: Dict[str, Any], spec: KratosSpec) -> PackedLinear:
     return PackedLinear(buffers=buffers, n_in=n_in, n_out=n_out, spec=spec)
 
 
+# TPU vector tiles are (8, 128): a Pallas block's last dim must be a
+# multiple of the 128 lanes. The block-sparse kernels stream x tiles
+# (bm, bk) and weight blocks (bk, bn), so both block extents sit on lanes.
+PALLAS_LANES = 128
+
+
+def check_pallas_blocks(packed: PackedLinear) -> None:
+    """Raise unless the block-sparse Pallas kernels can tile `packed`.
+
+    Dense and quantized buffers fit their GEMM blocks to the projection
+    (kernels.ops._fit_block); gathered sparsity blocks keep the pack-time
+    (bk, bn), which the TPU refuses below the lane width. Small blocks stay
+    valid for backend='ref' and 'interpret'."""
+    if packed.spec is None or not {"blocks", "qblocks"} & set(packed.buffers):
+        return
+    bk, bn = packed.spec.bk, packed.spec.bn
+    if bk % PALLAS_LANES or bn % PALLAS_LANES:
+        raise ValueError(
+            f"backend='pallas' needs sparsity blocks that are multiples of "
+            f"{PALLAS_LANES} (the TPU lane width); this {packed.n_in}x"
+            f"{packed.n_out} projection was packed with bk={bk}, bn={bn}. "
+            f"Pack with bk=bn={PALLAS_LANES}, or serve with backend='ref'.")
+
+
 def serving_spec(n_in: int, n_out: int, spec: KratosSpec) -> KratosSpec:
     """Degrade an arch-wide spec to what a given projection can pack.
 

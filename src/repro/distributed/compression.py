@@ -67,14 +67,12 @@ def cross_pod_psum_int8(x: jnp.ndarray, mesh, axis: str = "pod") -> jnp.ndarray:
     holds its own partial sum; we quantize the partial, reduce the integer
     codes, and dequantize with the max scale.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_axes = len(mesh.axis_names)
     spec = P(*([None] * x.ndim))
 
     @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=spec, out_specs=spec, check_rep=False)
+        jax.shard_map, mesh=mesh,
+        in_specs=spec, out_specs=spec, check_vma=False)
     def reduce_fn(xx):
         q, scale = _quant_int8(xx)
         # shared scale: use the max scale across pods so codes are comparable

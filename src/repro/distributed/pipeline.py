@@ -28,7 +28,6 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -51,8 +50,8 @@ def pipeline_apply(
     in_specs = (pspec_params, P(None))          # params sharded, x replicated
     out_specs = P(None)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     def run(params, xs):
         # params: leaves (1, ...) — this pod's stage; xs: (n_micro, mb, d)
         params = jax.tree_util.tree_map(lambda p: p[0], params)

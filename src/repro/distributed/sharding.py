@@ -89,10 +89,8 @@ def use_mesh(mesh: Mesh, rule_overrides: Optional[Dict] = None):
     prev = L._LOGICAL_RESOLVER
     L.set_logical_resolver(_resolver_for(mesh, rule_overrides))
     try:
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else contextlib.nullcontext():
-            with mesh:
-                yield mesh
+        with jax.set_mesh(mesh):
+            yield mesh
     finally:
         L.set_logical_resolver(prev)
 

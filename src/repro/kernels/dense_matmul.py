@@ -68,7 +68,7 @@ def dense_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, p), x.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

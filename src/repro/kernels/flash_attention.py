@@ -130,7 +130,7 @@ def flash_attention(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -269,7 +269,7 @@ def paged_flash_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_pad, d), q.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table.astype(jnp.int32), last.astype(jnp.int32), q, k_pages, v_pages)

@@ -14,6 +14,14 @@ sublane-aligned size, zero-pad m up to it and slice the result back
 (pallas_compat.skinny_bm / pad_rows). `SKINNY_M_EVENTS` (re-exported here)
 records each padded dispatch at trace time so serving benchmarks can assert
 the decode GEMMs really run the packed Pallas path at slab width.
+
+Meshes: Mosaic kernels cannot be partitioned automatically, so under a
+mesh of more than one device (`jax.set_mesh`, as ShardedBackend traces)
+every kernel call is wrapped in a `shard_map` with replicated operands and
+result (`_per_device`): each device runs the whole kernel on gathered
+inputs. The packed weight buffers are replicated anyway
+(sharding.param_pspecs), so this costs the gathers of the activations, and
+it gives up tensor-parallel compute inside the packed GEMMs.
 """
 
 from __future__ import annotations
@@ -23,6 +31,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import quantize as qz
 from repro.kernels import ref as _ref
@@ -53,6 +62,17 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
+def _per_device(kernel, *args):
+    """Run a Pallas `kernel(*args)` whole on every device of the current
+    mesh (see module docstring); a plain call off a multi-device mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel(*args)
+    specs = jax.tree_util.tree_map(lambda _: P(), args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=P(),
+                         check_vma=False)(*args)
+
+
 def _fit_block(block: int, dim: int) -> int:
     """Largest power-of-two block <= `block` that divides `dim`."""
     c = min(block, dim)
@@ -73,7 +93,9 @@ def matmul(x, w, *, backend: str = "auto", bm: int = 128, bk: int = 128,
     # sublane-aligned block (fitting bm to e.g. m=4 would force sub-sublane
     # tiles that the TPU cannot lay out).
     bk, bn = _fit_block(bk, n), _fit_block(bn, p)
-    return _dense_pallas(x, w, bm=bm, bk=bk, bn=bn, interpret=(b == "interpret"))
+    return _per_device(functools.partial(
+        _dense_pallas, bm=bm, bk=bk, bn=bn, interpret=(b == "interpret")),
+        x, w)
 
 
 def bsr_matmul(x, blocks, indices, *, backend: str = "auto", bm: int = 128):
@@ -81,8 +103,9 @@ def bsr_matmul(x, blocks, indices, *, backend: str = "auto", bm: int = 128):
     b = resolve_backend(backend)
     if b == "ref":
         return _ref.bsr_matmul_scan_ref(x, blocks, indices)
-    return _bsr_pallas(x, blocks, indices, bm=bm,
-                       interpret=(b == "interpret"))
+    return _per_device(functools.partial(
+        _bsr_pallas, bm=bm, interpret=(b == "interpret")),
+        x, blocks, indices)
 
 
 def _fit_quant_blocks(qt, bk: int, bn: int):
@@ -102,7 +125,9 @@ def quant_matmul(x, qt: qz.QuantizedTensor, *, backend: str = "auto",
     if b == "ref":
         return _ref.quant_matmul_ref(x, qt)
     bk, bn = _fit_quant_blocks(qt, bk, bn)
-    return _quant_pallas(x, qt, bm=bm, bk=bk, bn=bn, interpret=(b == "interpret"))
+    return _per_device(functools.partial(
+        _quant_pallas, bm=bm, bk=bk, bn=bn, interpret=(b == "interpret")),
+        x, qt)
 
 
 def quant_matmul_w8a8(x, qt: qz.QuantizedTensor, *, backend: str = "auto",
@@ -111,7 +136,9 @@ def quant_matmul_w8a8(x, qt: qz.QuantizedTensor, *, backend: str = "auto",
     if b == "ref":
         return _ref.quant_matmul_w8a8_ref(x, qt)
     bk, bn = _fit_quant_blocks(qt, bk, bn)
-    return _w8a8_pallas(x, qt, bm=bm, bk=bk, bn=bn, interpret=(b == "interpret"))
+    return _per_device(functools.partial(
+        _w8a8_pallas, bm=bm, bk=bk, bn=bn, interpret=(b == "interpret")),
+        x, qt)
 
 
 def bsr_quant_matmul(x, qblocks, scales, indices, bits: int, *,
@@ -120,8 +147,9 @@ def bsr_quant_matmul(x, qblocks, scales, indices, bits: int, *,
     b = resolve_backend(backend)
     if b == "ref":
         return _ref.bsr_quant_matmul_ref(x, qblocks, scales, indices, bits)
-    return _bsr_quant_pallas(x, qblocks, scales, indices, bits, bm=bm,
-                             interpret=(b == "interpret"))
+    return _per_device(functools.partial(
+        _bsr_quant_pallas, bits=bits, bm=bm, interpret=(b == "interpret")),
+        x, qblocks, scales, indices)
 
 
 def ssm_scan(u, dt, b, c, a, *, backend: str = "auto", bd: int = 128,
@@ -131,8 +159,9 @@ def ssm_scan(u, dt, b, c, a, *, backend: str = "auto", bd: int = 128,
     bk = resolve_backend(backend)
     if bk == "ref":
         return _ssm.ssm_scan_ref(u, dt, b, c, a)
-    return _ssm.ssm_scan(u, dt, b, c, a, bd=bd, ck=ck,
-                         interpret=(bk == "interpret"))
+    return _per_device(functools.partial(
+        _ssm.ssm_scan, bd=bd, ck=ck, interpret=(bk == "interpret")),
+        u, dt, b, c, a)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
@@ -148,11 +177,12 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         vv = jnp.repeat(v, g, axis=1) if g > 1 else v
         return _ref.attention_ref(q, kk, vv, causal=causal, window=window,
                                   softcap=softcap, q_offset=q_offset, scale=scale)
-    out = _fa_pallas(
+    out = _per_device(functools.partial(
+        _fa_pallas, causal=causal, window=window, softcap=softcap,
+        q_offset=q_offset, scale=scale, bq=bq, bkv=bkv,
+        interpret=(bk == "interpret")),
         q.reshape(b * h, sq, d), k.reshape(b * h_kv, skv, d),
-        v.reshape(b * h_kv, skv, d),
-        causal=causal, window=window, softcap=softcap, q_offset=q_offset,
-        scale=scale, bq=bq, bkv=bkv, interpret=(bk == "interpret"))
+        v.reshape(b * h_kv, skv, d))
     return out.reshape(b, h, sq, d)
 
 
@@ -172,6 +202,7 @@ def paged_attention(q, k_pages, v_pages, table, last, *, window=None,
         return _ref.paged_attention_ref(
             q, k_pages, v_pages, table, last,
             window=window, softcap=softcap, scale=scale)
-    return _paged_fa_pallas(
-        q, k_pages, v_pages, table, last, window=window, softcap=softcap,
-        scale=scale, interpret=(bk == "interpret"))
+    return _per_device(functools.partial(
+        _paged_fa_pallas, window=window, softcap=softcap, scale=scale,
+        interpret=(bk == "interpret")),
+        q, k_pages, v_pages, table, last)

@@ -1,10 +1,6 @@
-"""Version compatibility + shared tiling helpers for the Pallas TPU kernels.
+"""Shared tiling helpers for the Pallas TPU kernels.
 
-jax renamed `pltpu.TPUCompilerParams` -> `pltpu.CompilerParams`; the kernels
-are written against the new name and this shim resolves whichever the
-installed jax provides.
-
-Also hosts the skinny-m row-padding helpers shared by every GEMM kernel:
+Hosts the skinny-m row-padding helpers shared by every GEMM kernel:
 decode batches are m = n_slots (4-ish) rows while the kernels tile m in
 MXU-sized blocks, so each kernel pads m up to a sublane-aligned block and
 slices the result back (`pad_rows` / `skinny_bm`). Events are recorded in
@@ -17,23 +13,8 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as _pltpu
 
 from repro.instrument import REGISTRY
-
-CompilerParams = getattr(_pltpu, "CompilerParams",
-                         getattr(_pltpu, "TPUCompilerParams", None))
-
-if CompilerParams is None:                             # pragma: no cover
-    class CompilerParams:  # type: ignore[no-redef]
-        """Fail loudly at construction, not with a NoneType call error."""
-
-        def __init__(self, *args, **kwargs):
-            raise ImportError(
-                "this jax exposes neither pallas-TPU CompilerParams nor "
-                "TPUCompilerParams; update repro.kernels.pallas_compat for "
-                "the installed jax version")
-
 
 # ---------------------------------------------------------------------------
 # Skinny-m support (decode GEMMs: m = n_slots << 128)

@@ -32,20 +32,24 @@ from repro.core import quantize as qz
 
 
 def _unpack_tile(wq: jnp.ndarray, bits: int) -> jnp.ndarray:
-    """int8[(bk/vpb), bn] packed -> int8[bk, bn] codes (in-kernel)."""
+    """int8[(bk/vpb), bn] packed -> int8[bk, bn] codes (in-kernel).
+
+    Fields are extracted in int32: Mosaic cannot legalize shifts on 8-bit
+    vectors, so each byte is widened once (its low 8 bits are the raw
+    field bits) and shifted/masked as a 32-bit lane value."""
     if bits == 8:
         return wq
     vpb = qz.VALUES_PER_BYTE[bits]
     mask = (1 << bits) - 1
     sign = 1 << (bits - 1)
-    pu = wq.astype(jnp.uint8)
+    pu = wq.astype(jnp.int32) & 0xFF
     fields = []
     for i in range(vpb):
-        f = (pu >> jnp.uint8(i * bits)) & mask
+        f = (pu >> (i * bits)) & mask
         if bits == 1:
-            f = f.astype(jnp.int32) * 2 - 1
+            f = f * 2 - 1
         else:
-            f = (f.astype(jnp.int32) ^ sign) - sign
+            f = (f ^ sign) - sign
         fields.append(f.astype(jnp.int8))
     tile = jnp.stack(fields, axis=1)                # (bk/vpb, vpb, bn)
     return tile.reshape(wq.shape[0] * vpb, wq.shape[1])
@@ -106,7 +110,7 @@ def quant_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, p), x.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, qt.data, scale2d)
@@ -171,7 +175,7 @@ def quant_matmul_w8a8(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         out_shape=jax.ShapeDtypeStruct((m, p), x.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, xs, qt.data, qt.scale.reshape(1, p))
@@ -193,7 +197,7 @@ def _bsr_wq_kernel(idx_ref, x_ref, b_ref, s_ref, o_ref, acc_ref,
 
     @pl.when(t == nnz - 1)
     def _flush():
-        o_ref[...] = (acc_ref[...] * s_ref[0].astype(jnp.float32)
+        o_ref[...] = (acc_ref[...] * s_ref[...].astype(jnp.float32)
                       ).astype(o_ref.dtype)
 
 
@@ -229,7 +233,10 @@ def bsr_quant_matmul(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, t, idx: (i, idx[j, t])),
             pl.BlockSpec((1, 1, bkp, bn), lambda i, j, t, idx: (j, t, 0, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, t, idx: (j, 0)),
+            # scales as one (1, n_pb * bn) row: a (1, bn) block over
+            # (n_pb, bn) would put a 1 on the sublane axis of a multi-row
+            # array, which the TPU's (8, 128) tiling refuses
+            pl.BlockSpec((1, bn), lambda i, j, t, idx: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t, idx: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
@@ -239,8 +246,9 @@ def bsr_quant_matmul(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n_pb * bn), x.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray(indices, jnp.int32), x, qblocks, scales)
+    )(jnp.asarray(indices, jnp.int32), x, qblocks,
+      scales.reshape(1, n_pb * bn))
     return out if m == m_orig else out[:m_orig]

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat as _compat
-
 
 def _ssm_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, h_ref, state_ref,
                 *, ck: int, n_ck: int, return_final: bool):
@@ -102,7 +100,7 @@ def ssm_scan(
             jax.ShapeDtypeStruct((bsz, di, st), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((st, bd), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, dt, b, c, a)

@@ -204,6 +204,31 @@ class Fleet:
         return None
 
 
+def check_parent_platform() -> None:
+    """Refuse to spawn workers from a process that already holds a TPU."""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() \
+            and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "spawn_fleet: this process already initialized jax on a TPU "
+            "and holds the chip, so no worker process could use it; spawn "
+            "the fleet before touching jax, or serve in this one process")
+
+
+def worker_env(devices: int) -> dict:
+    """A worker's environment: the parent's (JAX_PLATFORMS included, so a
+    worker runs where the parent would), plus its own forced CPU device
+    count and this checkout's `src` on PYTHONPATH."""
+    repo_src = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = repo_src + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    return env
+
+
 def spawn_fleet(n_processes: int, *, arch: str = "h2o-danube-1.8b",
                 n_slots: int = 4, max_len: int = 96, decode_chunk: int = 4,
                 replicas_per_process: int = 1,
@@ -211,28 +236,24 @@ def spawn_fleet(n_processes: int, *, arch: str = "h2o-danube-1.8b",
                 jax_coordinator: str = "", heartbeat_every: int = 2,
                 cfg=None, hello_timeout: float = 300.0) -> Fleet:
     """Spawn `n_processes` worker subprocesses and return a Fleet whose
-    router admits across them. Workers force their own CPU device counts
+    router admits across them. Workers run on the parent's platform (they
+    inherit JAX_PLATFORMS, if set) and force their own CPU device counts
     (XLA_FLAGS in the child env — set BEFORE the child imports jax, the
-    only reliable point to do it), so the parent's jax state is never
-    touched: spawn_fleet is safe to call from pytest or a bench that
-    already initialized jax."""
+    only reliable point to do it). On CPU the parent's jax state is never
+    touched, so spawn_fleet is safe to call from pytest or a bench that
+    already initialized jax; a parent that holds a TPU is refused, since
+    a chip belongs to one process and the workers could not reach it."""
     from repro.serve import FleetConfig, FleetRouter
     from repro.serve.control import ControlListener, RemoteProcess
 
+    check_parent_platform()
     listener = ControlListener()
     if jax_coordinator == "auto":
         jax_coordinator = f"127.0.0.1:{_free_port()}"
     devices = devices_per_process or replicas_per_process
-    repo_src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
     workers = []
     for i in range(n_processes):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={devices}")
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-        env["PYTHONPATH"] = repo_src + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+        env = worker_env(devices)
         cmd = [sys.executable, "-m", "repro.launch.fleet", "--worker",
                "--connect", listener.address,
                "--process-index", str(i), "--processes", str(n_processes),

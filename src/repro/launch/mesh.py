@@ -16,17 +16,33 @@ from __future__ import annotations
 from typing import List, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+from repro.analysis import roofline as RL
+
+
+def make_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with every axis `AxisType.Auto`.
+
+    jax 0.9 defaults to Explicit axes, under which sharding is part of each
+    array's type and ops such as the embedding gather refuse operands whose
+    sharding they cannot infer. The serving and training steps are written
+    for GSPMD propagation (Auto): placement comes from in/out shardings and
+    `with_sharding_constraint`, never from the types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_arg(spec: str) -> Tuple[int, int]:
@@ -166,9 +182,10 @@ def plan_fleet_topology(n_processes: int, devices_per_process: int,
     }
 
 
-# Hardware constants for the roofline (TPU v5e-class, per chip)
-PEAK_BF16_FLOPS = 197e12        # FLOP/s
+# Hardware constants for the dry-run roofline (TPU v5e, per chip); the
+# bf16 and HBM peaks come from analysis.roofline's published-peaks table
+PEAK_BF16_FLOPS = RL.PEAKS["TPU v5 lite"].peak_flops    # FLOP/s
 PEAK_INT8_OPS = 394e12          # OP/s
-HBM_BW = 819e9                  # B/s
+HBM_BW = RL.PEAKS["TPU v5 lite"].peak_bw                # B/s
 ICI_BW_PER_LINK = 50e9          # B/s per link (~ per-axis-neighbor)
 HBM_BYTES = 16 * 2 ** 30        # 16 GiB

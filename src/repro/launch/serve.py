@@ -2,8 +2,9 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch h2o-danube-1.8b \
       --requests 8 --prompt-len 32 --gen 32 \
-      [--sparsity 0.5 --bits 8 --impl tree] [--slots 4] [--static] \
-      [--temperature 0.8] \
+      [--full --backend pallas] \
+      [--sparsity 0.5 --bits 8 --impl tree --block 128] [--slots 4] \
+      [--static] [--temperature 0.8] \
       [--speculate 4 --draft-bits 8 [--draft-sparsity S] \
        [--draft-keep-layers N]] \
       [--page-size P [--n-pages N] [--no-prefix-cache]] \
@@ -57,8 +58,9 @@ fidelity, ~1.0 acceptance), add sparsity / layer truncation to cut draft
 FLOPs while acceptance stays above ~0.8; the engine report prints the
 acceptance rate and draft/verify FLOP ratio to steer by.
 
-Loads the reduced config on CPU through the serve registry (weights packed
-once via kratos.pack), submits `--requests` generation requests with a small
+Loads the reduced smoke config through the serve registry (weights packed
+once via kratos.pack) — or, with `--full`, the published config at its
+published widths — submits `--requests` generation requests with a small
 prompt-length jitter, and drives the engine until the trace drains. The
 engine's prefill/decode steps are the SAME `distributed.steps` factories the
 decode_32k / long_500k dry-run cells lower for the production mesh — the
@@ -285,7 +287,17 @@ def main() -> None:
     ap.add_argument("--bits", type=int, default=0, help="0 = native bf16/f32")
     ap.add_argument("--act-bits", type=int, default=0, help="8 => w8a8")
     ap.add_argument("--impl", default="tree", choices=("tree", "systolic"))
-    ap.add_argument("--block", type=int, default=8, help="sparsity bk=bn")
+    ap.add_argument("--block", type=int, default=8,
+                    help="sparsity bk=bn (8 serves on ref/interpret only; "
+                         "--backend pallas needs a multiple of 128)")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config (all layers, published "
+                         "widths; weights from --seed) instead of the "
+                         "reduced smoke config")
+    ap.add_argument("--backend", default="ref",
+                    choices=("ref", "pallas", "interpret"),
+                    help="GEMM/attention kernels: XLA reference, Pallas on "
+                         "the TPU, or Pallas in interpret mode on the CPU")
     ap.add_argument("--static", action="store_true",
                     help="lock-step drain-then-refill baseline scheduler")
     ap.add_argument("--temperature", type=float, default=0.0)
@@ -404,7 +416,8 @@ def main() -> None:
                     help="this process's index for --coordinator")
     args = ap.parse_args()
 
-    from repro.launch import mesh as M
+    from repro.launch import compile_cache, mesh as M
+    compile_cache.configure()
 
     fleet_mode = args.processes > 1 or args.coordinator
     if args.dry_run and fleet_mode:
@@ -433,7 +446,7 @@ def main() -> None:
     tier_specs = parse_tiers(args.tiers) if args.tiers else ()
     registry = ModelRegistry()
     model = registry.load(args.arch, spec, seed=args.seed, draft_spec=draft,
-                          tier_specs=tier_specs)
+                          tier_specs=tier_specs, smoke=not args.full)
     print(f"[serve] {model.name}: {model.n_packed} packed projections, "
           f"{model.packed_bytes / 1e6:.2f} MB packed vs "
           f"{model.dense_bytes / 1e6:.2f} MB dense "
@@ -469,6 +482,7 @@ def main() -> None:
                     page_pressure=args.qos_page_pressure) \
         if tier_specs else None
     cfg = EngineConfig(n_slots=args.slots, max_len=max_len, seed=args.seed,
+                       backend=args.backend,
                        device_loop=not args.host_loop,
                        decode_chunk=args.decode_chunk,
                        speculate=args.speculate,

@@ -13,12 +13,12 @@ carry the batch axis at dim 0; scanned 'blocks' entries are layer-stacked,
 so their batch axis is dim 1. `write_slot` maps over the two groups with the
 right axis — the only place in the serving stack that knows this.
 
-Donation: `write_slot` donates BOTH the slab and the incoming batch-1 tree
-(`donate_argnums=(0, 1)`), so on backends with buffer donation (TPU/GPU) the
-slot install is an in-place row write — the slab is never copied per
-admission, and the prefill's cache output buffers are recycled. On CPU, XLA
-has no donation and falls back to a copy (the warning is filtered: it is the
-expected, documented fallback, not a bug).
+Donation: `write_slot` donates the slab (`donate_argnums=(0,)`), so on
+backends with buffer donation (TPU/GPU) the slot install is an in-place row
+write — the slab is never copied per admission. The incoming batch-1 tree is
+not donated: no output has its shape, so XLA could not reuse it and would
+warn. On CPU, XLA has no donation and falls back to a copy (the warning is
+filtered: it is the expected, documented fallback, not a bug).
 
 Mesh placement: constructed with `mesh=`, the pool resolves one
 `NamedSharding` per cache leaf via `sharding.cache_pspecs(..., slab=True)`
@@ -49,7 +49,11 @@ def quiet_donation():
     The serving hot path donates buffers (in-place on TPU/GPU); CPU has no
     donation and warns before falling back to a copy — expected, documented
     behavior, suppressed ONLY around our own donating dispatches so a user's
-    broken donate_argnums elsewhere still warns."""
+    broken donate_argnums elsewhere still warns. On any other backend the
+    warning means a donated buffer was copied, a bug, so it stays loud."""
+    if jax.default_backend() != "cpu":
+        yield
+        return
     with warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
@@ -93,9 +97,8 @@ class CachePool:
         self.caches = T.make_caches(cfg, n_slots, max_len, dtype)
         self._free: List[int] = list(range(n_slots - 1, -1, -1))
         self.shardings = None
-        # donate slab AND single: the slot install updates the slab row in
-        # place and recycles the prefill's output buffers (no per-admission
-        # slab copy; see module docstring).
+        # donate the slab: the slot install updates its row in place (no
+        # per-admission slab copy; see module docstring)
         if mesh is not None:
             from jax.sharding import NamedSharding
             from repro.distributed import sharding as SH
@@ -103,10 +106,10 @@ class CachePool:
             self.shardings = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s), pspecs)
             self.caches = jax.device_put(self.caches, self.shardings)
-            self._write = jax.jit(_write_tree, donate_argnums=(0, 1),
+            self._write = jax.jit(_write_tree, donate_argnums=(0,),
                                   out_shardings=self.shardings)
         else:
-            self._write = jax.jit(_write_tree, donate_argnums=(0, 1))
+            self._write = jax.jit(_write_tree, donate_argnums=(0,))
         self._single_template = None
 
     @property

@@ -264,6 +264,7 @@ class InferenceEngine:
                     f"load the model with registry.load(..., draft_spec=Draft"
                     f"Spec(...)); '{model.name}' has none")
             SP.check_supported(model.cfg, cfg.max_len + cfg.speculate)
+        model.check_backend(cfg.backend)
         self.model = model
         self.cfg = cfg
         mcfg = model.cfg

@@ -261,7 +261,7 @@ class PageLayout:
 
 def _install_one(layout: PageLayout):
     """(store, single, page_table, slot) -> store: slot install, jittable
-    with (store, single) donated — the paged analogue of CachePool._write."""
+    with the store donated — the paged analogue of CachePool._write."""
     def install(store, single, page_table, slot):
         row = jax.lax.dynamic_index_in_dim(page_table, slot, axis=0,
                                            keepdims=False)
@@ -334,13 +334,13 @@ class PagedCachePool:
             self.page_table = jax.device_put(self.page_table,
                                              self.table_sharding)
             self._write = jax.jit(_install_one(self.layout),
-                                  donate_argnums=(0, 1),
+                                  donate_argnums=(0,),
                                   out_shardings=self.shardings)
             self._set = jax.jit(_set_row, donate_argnums=(0,),
                                 out_shardings=self.table_sharding)
         else:
             self._write = jax.jit(_install_one(self.layout),
-                                  donate_argnums=(0, 1))
+                                  donate_argnums=(0,))
             self._set = jax.jit(_set_row, donate_argnums=(0,))
 
     # -------------------------------------------------------------- slots

@@ -129,6 +129,16 @@ class PackedModel:
             return 1.0
         return SP.draft_cost_fraction(self.cfg, self.draft_cfg)
 
+    def check_backend(self, backend: str) -> None:
+        """Raise before any compile if a packed projection of this artifact
+        (target, draft or QoS tier) cannot run on `backend` — today only
+        the Pallas tiling of sparsity blocks (kratos.check_pallas_blocks)."""
+        if backend != "pallas":
+            return
+        for tree in (self.params, self.draft_params, *self.tier_params):
+            for pl in _iter_packed(tree):
+                kr.check_pallas_blocks(pl)
+
     def pspecs(self, mesh) -> Any:
         """Parameter PartitionSpec tree for serving this artifact on `mesh`
         (sharding.param_pspecs): name-rule FSDP x TP where shapes divide;

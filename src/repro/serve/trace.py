@@ -43,8 +43,9 @@ Profiler capture: `TraceConfig.profile_dir` brackets the first
 `profile_dispatches` traced decode dispatches with
 `jax.profiler.start_trace/stop_trace`, so the DEVICE-side timeline of the
 fused step lands next to the host-side spans (one TensorBoard/Perfetto
-capture per run; the bracket degrades to a no-op where the profiler is
-unavailable, e.g. some CPU-only wheels).
+capture per run). A profiler that fails to start or stop raises: a
+capture that was asked for and silently missing would read as "no device
+activity".
 
 The ring buffer (`capacity` events, default 64k) makes tracing safe to
 leave on under sustained traffic: old events fall off the head (counted in
@@ -252,7 +253,7 @@ class Tracer(NullTracer):
     def dispatch_begin(self):
         self._t0d = self._t()
         if self._profile_left and not self._profiling:
-            self._profiling = self._profiler_start()
+            self._profiler_start()
 
     def decode_dispatch(self, k, n_active, n_slots):
         t = self._t()
@@ -342,28 +343,21 @@ class Tracer(NullTracer):
 
     # -- profiler bracket ---------------------------------------------------
 
-    def _profiler_start(self) -> bool:
-        try:
-            import jax
-            jax.profiler.start_trace(self.cfg.profile_dir)
-            self._push({"ev": "profile_start", "step": self.step,
-                        "t": self._t(), "dir": self.cfg.profile_dir,
-                        "dispatches": self.cfg.profile_dispatches})
-            return True
-        except Exception:           # profiler unavailable on this substrate
-            self._profile_left = 0
-            return False
+    def _profiler_start(self) -> None:
+        import jax
+        jax.profiler.start_trace(self.cfg.profile_dir)
+        self._profiling = True
+        self._push({"ev": "profile_start", "step": self.step,
+                    "t": self._t(), "dir": self.cfg.profile_dir,
+                    "dispatches": self.cfg.profile_dispatches})
 
     def _profiler_tick(self) -> None:
         if not self._profiling:
             return
         self._profile_left -= 1
         if self._profile_left <= 0:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            import jax
+            jax.profiler.stop_trace()
             self._profiling = False
             self._push({"ev": "profile_stop", "step": self.step,
                         "t": self._t()})

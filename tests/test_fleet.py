@@ -209,3 +209,21 @@ def test_telemetry_process_index_label():
     assert 'serve_latency_count{process_index="3"}' in labeled
     # same metric set, only the label differs
     assert len(plain.splitlines()) == len(labeled.splitlines())
+
+
+def test_workers_inherit_platform_and_tpu_parent_is_refused(monkeypatch):
+    """Workers run where the parent would (no forced CPU), and a parent
+    that already holds a TPU cannot spawn workers that need it."""
+    import jax
+    from repro.launch import fleet as F
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert F.worker_env(2)["JAX_PLATFORMS"] == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    env = F.worker_env(2)
+    assert "JAX_PLATFORMS" not in env
+    assert env["XLA_FLAGS"] == "--xla_force_host_platform_device_count=2"
+    jax.devices()                         # this process has initialized jax
+    F.check_parent_platform()             # ... on the CPU: fine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        F.spawn_fleet(2)

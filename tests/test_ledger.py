@@ -267,3 +267,23 @@ def test_sink_delta_and_rebase():
     cum3[0, C_ELEMS] = 5.0
     sink.on_drain(cum3, step=3)
     assert sink.total[0, C_ELEMS] == 30.0
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the published table by device kind; an unknown kind
+    raises instead of falling back to assumed numbers."""
+    from repro.analysis import roofline as RL
+    v5e = RL.peaks_for("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.peak_bw) == (197e12, 819e9) and v5e.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        RL.peaks_for("cpu")
+    # explicit peaks for a CPU-side check of the arithmetic
+    peaks = RL.Peaks(peak_flops=100.0, peak_bw=10.0)
+    mem = RL.roofline_point(50.0, 10.0, 1.0, peaks)      # intensity 5 < 10
+    assert mem["bound"] == "memory" and mem["utilization"] == 1.0
+    comp = RL.roofline_point(400.0, 10.0, 8.0, peaks)    # intensity 40
+    assert comp["bound"] == "compute" and comp["utilization"] == 0.5
+    rep = RL.replica_roofline({"flops_dense": 400.0, "flops_effective": 200.0,
+                               "bytes_dense": 10.0, "bytes_effective": 5.0},
+                              8.0, peaks=peaks)
+    assert rep["skip_speedup_bound"] == 2.0

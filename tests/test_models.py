@@ -290,3 +290,20 @@ def test_kratos_spec_through_full_model():
     from repro.core import sparsity as sp
     mask = sp.plan_mask(plan)
     np.testing.assert_allclose(np.asarray(wq) * (1 - mask), 0.0, atol=1e-6)
+
+
+def test_stack_layers_matches_stack_and_releases_layers():
+    """Init stacks the scanned layers leaf by leaf into the same arrays a
+    whole-tree stack gives, and lets go of the per-layer list it was given
+    (eager init at published widths would otherwise hold two copies)."""
+    cfg = C.get_smoke("h2o-danube-1.8b")
+    layers = [T._layer_init(jax.random.PRNGKey(i), cfg, 0) for i in range(3)]
+    want = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *layers)
+    given = list(layers)
+    got = T._stack_layers(given)
+    assert given == []
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -83,10 +83,9 @@ def test_cross_pod_int8_psum():
         x = jnp.stack(xs)                   # (8, 32, 32)
         sh = NamedSharding(mesh, P("pod"))
         xd = jax.device_put(x, sh)
-        from jax.experimental.shard_map import shard_map
         import functools
-        @functools.partial(shard_map, mesh=mesh, in_specs=P("pod"),
-                           out_specs=P("pod"), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+                           out_specs=P("pod"), check_vma=False)
         def reduce_fn(xx):
             from repro.distributed.compression import _quant_int8
             q, scale = _quant_int8(xx[0])
@@ -107,8 +106,9 @@ def test_elastic_remesh_restore():
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.manager import CheckpointManager
-        mesh_a = jax.make_mesh((8, 1), ("data", "model"))
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh_a = make_mesh((8, 1), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         tree = {"w": jax.device_put(
             jnp.arange(64.0).reshape(8, 8),
             NamedSharding(mesh_a, P("data", None)))}
@@ -144,7 +144,8 @@ def test_spmd_train_step_8dev_matches_1dev():
         step = ST.make_train_step(cfg, opt)
         _, m_ref = jax.jit(step)(state, batch)     # default: 1-device exec
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         with SH.use_mesh(mesh):
             psh = SH.param_shardings(mesh, state["params"])
             st_sh = {"params": psh,
@@ -170,8 +171,9 @@ def test_mini_dryrun_smoke_config_on_8dev_mesh():
         from repro.distributed import sharding as SH, steps as ST
         from repro.optim import adamw as O
         import jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         cfg = C.get_smoke("gemma2_27b")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         opt = O.OptimizerConfig()
         state = jax.eval_shape(
             lambda k: ST.init_train_state(k, cfg, opt),

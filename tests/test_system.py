@@ -105,3 +105,27 @@ def test_frontend_stubs_shapes():
     # determinism (fixed projections)
     np.testing.assert_array_equal(np.asarray(F.llava_patches(img, 64)),
                                   np.asarray(patches))
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """The entry points' cache lands where JAX_COMPILATION_CACHE_DIR says,
+    else in the checkout's fixed .jax_cache — never a temp or pid path."""
+    from pathlib import Path
+    from repro.launch import compile_cache as CC
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(CC.ENV, raising=False)
+    else:
+        monkeypatch.setenv(CC.ENV, str(tmp_path / env_dir))
+    try:
+        got = CC.configure()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    if env_dir is None:
+        root = Path(__file__).resolve().parents[1]
+        assert got == now == str(root / ".jax_cache")
+    else:
+        assert got == str(tmp_path / env_dir)
+        assert now == prev                  # JAX reads the variable itself

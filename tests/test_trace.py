@@ -358,3 +358,27 @@ def test_tracing_does_not_change_tokens():
         eng.run()
         outs.append([list(r.generated) for r in reqs])
     assert outs[0] == outs[1]
+
+
+def test_profiler_bracket_captures_or_raises(tmp_path):
+    """An asked-for profiler capture either lands on disk or raises: it is
+    never skipped in silence."""
+    import jax
+    eng = _engine(trace=TraceConfig(profile_dir=str(tmp_path / "ok"),
+                                    profile_dispatches=1))
+    eng.submit(np.arange(5), 3)
+    eng.run()
+    evs = [e["ev"] for e in eng.trace.events]
+    assert "profile_start" in evs and "profile_stop" in evs
+    assert list((tmp_path / "ok").rglob("*.xplane.pb"))
+
+    # a second capture cannot start while one is running
+    jax.profiler.start_trace(str(tmp_path / "outer"))
+    try:
+        eng = _engine(trace=TraceConfig(profile_dir=str(tmp_path / "inner"),
+                                        profile_dispatches=1))
+        eng.submit(np.arange(5), 3)
+        with pytest.raises(RuntimeError):
+            eng.run()
+    finally:
+        jax.profiler.stop_trace()
