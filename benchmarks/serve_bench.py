@@ -87,8 +87,8 @@ zeros > 0 (nemotron's squared-ReLU MLP), every counter and per-layer
 zero-group histogram bit-identical across the two runs (hist_checksum),
 host syncs == decode dispatches (the ledger drains inside the existing
 token sync), and an exact tier-0 quality shadow (top1 1.0, MAD 0.0). The
-JSON artifact adds an ungated roofline join (analysis.roofline over the
-tracer's dispatch walls); `--out results/BENCH_ledger.json` is the CI
+JSON artifact adds an ungated replica roofline (analysis.roofline over
+the run's wall time); `--out results/BENCH_ledger.json` is the CI
 artifact, diffed against its golden by benchmarks/qor.py.
 
 Provenance (PR 4): every JSON record is stamped with the git commit, jax
@@ -813,9 +813,9 @@ def run_ledger_trace(arch: str, n_requests: int, n_slots: int, seed: int,
       * quality probes: tier-0 shadow prefill of a single-tier engine must
         agree with itself exactly (top1 rate 1.0, logit MAD 0.0).
 
-    The JSON record carries the gated counters plus an UNGATED roofline
-    join (analysis.roofline over the tracer's dispatch walls) — wall time
-    is machine-dependent, the counters are not.
+    The JSON record carries the gated counters plus an UNGATED replica
+    roofline (analysis.roofline over the run's wall time) — wall time is
+    machine-dependent, the counters are not.
     """
     from repro.analysis import roofline as RL
     from repro.serve import LedgerConfig
@@ -857,19 +857,15 @@ def run_ledger_trace(arch: str, n_requests: int, n_slots: int, seed: int,
         and rep1["quality_logit_mad"] == 0.0
     ok = deterministic and zeros_ok and syncs_ok and quality_ok
 
-    # ungated roofline attribution: join the tracer's dispatch walls with
-    # the ledger counter tracks drained at the same steps
-    # (only against the device's published peaks — a CPU run has none)
+    # ungated roofline attribution of the replica's drained ledger totals
+    # over its wall time (only against the device's published peaks — a
+    # CPU run has none)
     kind = jax.devices()[0].device_kind
     if kind in RL.PEAKS:
-        peaks = RL.peaks_for(kind)
-        dispatch_rows = RL.dispatch_rooflines(list(eng1.trace.events),
-                                              peaks=peaks)
-        replica = RL.replica_roofline(sum1, wall1, peaks=peaks)
+        replica = RL.replica_roofline(sum1, wall1, peaks=RL.peaks_for(kind))
         bound = (f"skip bound {replica['skip_speedup_bound']:.2f}x "
                  f"({replica['dense']['bound']}-bound)")
     else:
-        dispatch_rows = []
         replica = f"not measured: no published peaks for {kind!r}"
         bound = "skip bound not measured"
 
@@ -918,10 +914,7 @@ def run_ledger_trace(arch: str, n_requests: int, n_slots: int, seed: int,
                        "n_requests": n_requests, "k_block": k_block,
                        "quality_every": quality_every, **prov,
                        "deterministic": deterministic,
-                       "roofline": {
-                           "replica": replica,
-                           "n_dispatch_rows": len(dispatch_rows),
-                           "dispatches": dispatch_rows[:16]},
+                       "roofline": {"replica": replica},
                        "zero_fraction_by_layer":
                            sum1["zero_fraction_by_layer"],
                        "records": records}, f, indent=2)

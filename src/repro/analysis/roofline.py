@@ -1,4 +1,4 @@
-"""Roofline attribution: ledger counts x HLO costs x trace wall-time.
+"""Roofline attribution: ledger counts x HLO costs x wall time.
 
 The three observability sources each answer one question:
 
@@ -7,16 +7,16 @@ The three observability sources each answer one question:
     effective-vs-dense FLOPs/bytes), measured in-graph on the step clock;
   * `analysis.hlo.analyze` (static, loop-aware) — what the compiled
     program MUST execute per dispatch, independent of data;
-  * `serve.trace` dispatch events — how long each dispatch actually TOOK.
+  * a wall time over the same window — how long the work actually TOOK.
 
 This module joins them. `roofline_point` classifies one (flops, bytes,
-wall) triple against a machine roof; `dispatch_rooflines` joins a trace
-event stream's per-step wall durations with the ledger's per-step
-effective fractions to place BOTH the dense point (what the hardware ran)
-and the effective point (what a sparsity-aware kernel would need to run)
-on the same roof — the gap between them is the activation-skip
-opportunity the ledger exists to measure. `replica_roofline` does the
-same once per replica from drained totals.
+wall) triple against a machine roof; `replica_roofline` places BOTH the
+dense point (what the hardware ran) and the effective point (what a
+sparsity-aware kernel would need to run) on the same roof, once per
+replica from drained totals — the gap between them is the activation-skip
+opportunity the ledger exists to measure. Per-op device time comes from
+a profiler capture, not from host-observed dispatch walls: the chip
+benchmark reads it by the program's named scopes (`bench/opscope.py`).
 
 No third-party deps; everything is plain dict/float so results serialize
 straight into bench JSON and qor gating.
@@ -25,7 +25,7 @@ straight into bench JSON and qor gating.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,66 +89,6 @@ def roofline_point(flops: float, bytes_: float, wall_s: float,
         "bound": bound,
         "utilization": utilization,
     }
-
-
-def _index_ledger(events: Iterable[Dict[str, Any]]) -> Dict[int, Dict[str, Any]]:
-    return {int(ev["step"]): ev for ev in events if ev.get("ev") == "ledger"}
-
-
-def dispatch_rooflines(events: Iterable[Dict[str, Any]],
-                       hlo_cost: Optional[Dict[str, Any]] = None, *,
-                       peaks: Peaks) -> List[Dict[str, Any]]:
-    """Per-dispatch roofline rows from one tracer's event list.
-
-    events: `Tracer.export()["events"]` (or the parsed JSONL) — the join
-    key is the step clock: each "decode"/"spec" dispatch event is matched
-    with the "ledger" event drained at the same step.
-
-    hlo_cost: `analysis.hlo.analyze(...)` output for the dispatch
-    executable — supplies the static per-dispatch bytes (and a FLOPs
-    cross-check for the ledger's dense count). Without it, bytes fall
-    back to the ledger's own dense-bytes counter scaled per dispatch.
-
-    Each row carries a `dense` point (what ran) and an `effective` point
-    (the same wall clock at ledger-measured effective FLOPs/bytes): the
-    utilization gap between them is the headroom an activation-skip
-    kernel could claim.
-    """
-    evs = list(events)
-    ledger_by_step = _index_ledger(evs)
-    rows: List[Dict[str, Any]] = []
-    for ev in evs:
-        if ev.get("ev") not in ("decode", "spec"):
-            continue
-        step = int(ev["step"])
-        led = ledger_by_step.get(step)
-        if led is None:
-            continue
-        wall = float(ev.get("dur", 0.0))
-        flops_dense = float(led["flops_dense"])
-        flops_eff = float(led["flops_eff"])
-        if hlo_cost is not None:
-            bytes_dense = float(hlo_cost["bytes"])
-            static_flops = float(hlo_cost["flops"])
-        else:
-            bytes_dense = flops_dense  # intensity-1 fallback, labeled below
-            static_flops = 0.0
-        eff_frac = float(led.get("eff_flop_frac", 1.0))
-        bytes_eff = bytes_dense * eff_frac
-        rows.append({
-            "step": step,
-            "kind": ev["ev"],
-            "wall_s": wall,
-            "flops_dense": flops_dense,
-            "flops_effective": flops_eff,
-            "static_flops": static_flops,
-            "bytes_source": "hlo" if hlo_cost is not None else "ledger",
-            "zero_frac": float(led.get("zero_frac", 0.0)),
-            "dead_kblock_frac": float(led.get("dead_frac", 0.0)),
-            "dense": roofline_point(flops_dense, bytes_dense, wall, peaks),
-            "effective": roofline_point(flops_eff, bytes_eff, wall, peaks),
-        })
-    return rows
 
 
 def replica_roofline(summary: Dict[str, Any], wall_s: float,

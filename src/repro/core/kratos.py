@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.core import quantize as qz
 from repro.core import sparsity as sp
+from repro.instrument import scope
 from repro.kernels import ops
 from repro.kernels import ref as kref
 
@@ -130,7 +131,7 @@ def _ste_quant(w: jnp.ndarray, bits: int) -> jnp.ndarray:
 
 
 def apply(params: Dict[str, Any], x: jnp.ndarray, spec: KratosSpec = DENSE,
-          *, backend: str = "ref") -> jnp.ndarray:
+          *, backend: str = "ref", name: str = "") -> jnp.ndarray:
     """Training-time application: y = x @ kratos(w).
 
     x: (..., n_in) -> (..., n_out). The tree path gathers only live blocks,
@@ -140,6 +141,9 @@ def apply(params: Dict[str, Any], x: jnp.ndarray, spec: KratosSpec = DENSE,
     A `PackedLinear` leaf (serving trees built by serve.registry) dispatches
     to `apply_packed`, so the hot decode path runs on packed buffers while
     every model call site stays oblivious.
+
+    name: the projection's name (`wq`, `w_down`, ...); its ops run under
+    the `gemm` contract scope with `name` below it (repro.instrument).
     """
     if isinstance(params, PackedLinear):
         # packed buffers are only meaningful under their pack-time spec —
@@ -147,36 +151,40 @@ def apply(params: Dict[str, Any], x: jnp.ndarray, spec: KratosSpec = DENSE,
         # the same weights (serve.qos tier swaps)
         return apply_packed(params.buffers, x,
                             spec if params.spec is None else params.spec,
-                            params.n_in, params.n_out, backend=backend)
-    w = params["w"]
-    n_in, n_out = w.shape
-    lead = x.shape[:-1]
-    xm = x.reshape(-1, n_in)
-    if spec.bits is not None:
-        w = _ste_quant(w, spec.bits)
-    plan = plan_for(n_in, n_out, spec)
-    if plan is None or spec.impl == "systolic":
-        if plan is not None:  # systolic: mask, but pay dense compute
-            w = w * jnp.asarray(sp.plan_mask(plan), w.dtype)
-        y = ops.matmul(xm, w.astype(x.dtype), backend=backend) \
-            if backend != "ref" else kref.dense_matmul_ref(xm, w.astype(x.dtype))
-    else:
-        blocks = sp.pack_blocks(w.astype(x.dtype), plan)
-        # CO-DESIGN constraint (DESIGN.md §7): the packed blocks must keep
-        # the weight's tensor-parallel output sharding — without this, the
-        # pack reshape/gather loses it, every device computes ALL output
-        # blocks, and the sparsity saving is eaten by replication. Requires
-        # the block width bn to divide the TP shard width (n_out / |model|):
-        # the sparsity granularity and the fabric's shard granularity are
-        # coupled — the paper's LUT-size lesson reappearing as TP geometry.
-        from repro.models import layers as L   # lazy: layers imports kratos
-        blocks = L.shard(blocks, "out_blocks", None, None, None)
-        if backend == "ref":
-            y = kref.bsr_matmul_ref(xm, blocks, plan.indices)
+                            params.n_in, params.n_out, backend=backend,
+                            name=name)
+    with scope("gemm", name):
+        w = params["w"]
+        n_in, n_out = w.shape
+        lead = x.shape[:-1]
+        xm = x.reshape(-1, n_in)
+        if spec.bits is not None:
+            w = _ste_quant(w, spec.bits)
+        plan = plan_for(n_in, n_out, spec)
+        if plan is None or spec.impl == "systolic":
+            if plan is not None:  # systolic: mask, but pay dense compute
+                w = w * jnp.asarray(sp.plan_mask(plan), w.dtype)
+            y = ops.matmul(xm, w.astype(x.dtype), backend=backend) \
+                if backend != "ref" \
+                else kref.dense_matmul_ref(xm, w.astype(x.dtype))
         else:
-            y = ops.bsr_matmul(xm, blocks, jnp.asarray(plan.indices),
-                               backend=backend)
-    return y.reshape(*lead, n_out)
+            blocks = sp.pack_blocks(w.astype(x.dtype), plan)
+            # CO-DESIGN constraint (DESIGN.md §7): the packed blocks must
+            # keep the weight's tensor-parallel output sharding — without
+            # this, the pack reshape/gather loses it, every device computes
+            # ALL output blocks, and the sparsity saving is eaten by
+            # replication. Requires the block width bn to divide the TP
+            # shard width (n_out / |model|): the sparsity granularity and
+            # the fabric's shard granularity are coupled — the paper's
+            # LUT-size lesson reappearing as TP geometry.
+            from repro.models import layers as L  # lazy: layers imports kratos
+            blocks = L.shard(blocks, "out_blocks", None, None, None)
+            if backend == "ref":
+                y = kref.bsr_matmul_ref(xm, blocks, plan.indices)
+            else:
+                y = ops.bsr_matmul(xm, blocks, jnp.asarray(plan.indices),
+                                   backend=backend)
+        return y.reshape(*lead, n_out)
 
 
 # ---------------------------------------------------------------------------
@@ -330,38 +338,41 @@ def pack(params: Dict[str, Any], spec: KratosSpec) -> Dict[str, Any]:
 
 
 def apply_packed(packed: Dict[str, Any], x: jnp.ndarray, spec: KratosSpec,
-                 n_in: int, n_out: int, *, backend: str = "ref") -> jnp.ndarray:
+                 n_in: int, n_out: int, *, backend: str = "ref",
+                 name: str = "") -> jnp.ndarray:
     """Inference-time application on packed buffers.
 
     Dispatch is keyed on WHICH buffers `pack()` produced (dense 'w',
     quantized 'qt', gathered 'blocks', quantized-gathered 'qblocks'), so a
     spec degraded at pack time (serving_spec) stays consistent here.
+    name: as in `apply`, the projection's name below the `gemm` scope.
     """
-    lead = x.shape[:-1]
-    xm = x.reshape(-1, n_in)
-    plan = None
-    if "blocks" in packed or "qblocks" in packed:
-        plan = plan_for(n_in, n_out, spec)
-    if "w" in packed:
-        y = kref.dense_matmul_ref(xm, packed["w"].astype(x.dtype)) \
-            if backend == "ref" else ops.matmul(xm, packed["w"].astype(x.dtype),
-                                                backend=backend)
-    elif "qt" in packed:
-        if spec.act_bits == 8 and packed["qt"].bits == 8:
-            y = ops.quant_matmul_w8a8(xm, packed["qt"], backend=backend)
+    with scope("gemm", name):
+        lead = x.shape[:-1]
+        xm = x.reshape(-1, n_in)
+        plan = None
+        if "blocks" in packed or "qblocks" in packed:
+            plan = plan_for(n_in, n_out, spec)
+        if "w" in packed:
+            w = packed["w"].astype(x.dtype)
+            y = kref.dense_matmul_ref(xm, w) if backend == "ref" \
+                else ops.matmul(xm, w, backend=backend)
+        elif "qt" in packed:
+            if spec.act_bits == 8 and packed["qt"].bits == 8:
+                y = ops.quant_matmul_w8a8(xm, packed["qt"], backend=backend)
+            else:
+                y = ops.quant_matmul(xm, packed["qt"], backend=backend)
+        elif "blocks" in packed:
+            if backend == "ref":
+                y = kref.bsr_matmul_ref(xm, packed["blocks"], plan.indices)
+            else:
+                y = ops.bsr_matmul(xm, packed["blocks"],
+                                   jnp.asarray(plan.indices), backend=backend)
         else:
-            y = ops.quant_matmul(xm, packed["qt"], backend=backend)
-    elif "blocks" in packed:
-        if backend == "ref":
-            y = kref.bsr_matmul_ref(xm, packed["blocks"], plan.indices)
-        else:
-            y = ops.bsr_matmul(xm, packed["blocks"],
-                               jnp.asarray(plan.indices), backend=backend)
-    else:
-        y = ops.bsr_quant_matmul(xm, packed["qblocks"], packed["qscale"],
-                                 jnp.asarray(plan.indices), spec.bits,
-                                 backend=backend)
-    return y.reshape(*lead, n_out)
+            y = ops.bsr_quant_matmul(xm, packed["qblocks"], packed["qscale"],
+                                     jnp.asarray(plan.indices), spec.bits,
+                                     backend=backend)
+        return y.reshape(*lead, n_out)
 
 
 # ---------------------------------------------------------------------------

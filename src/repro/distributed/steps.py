@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from repro.distributed.sharding import batch_pspec
+from repro.instrument import scope
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.optim import adamw as O
@@ -160,8 +161,9 @@ def make_prefill_step(cfg: T.ModelConfig, backend: str = "ref",
             return body(params, batch, caches)
     else:
         def prefill(params, batch):
-            return body(params, batch,
-                        T.make_caches(cfg, 1, cache_len, cache_dtype))
+            with scope("kv_write"):
+                caches = T.make_caches(cfg, 1, cache_len, cache_dtype)
+            return body(params, batch, caches)
     return prefill
 
 
@@ -253,32 +255,35 @@ def make_decode_step(cfg: T.ModelConfig, backend: str = "ref", *,
                 led = led + mat
             else:
                 logits, _, caches = out
-            key, sub = jax.random.split(st["key"])
-            tok = T.sample_tokens(logits[:, -1], sub, st["temperature"])
-            active = st["active"]
-            tok = jnp.where(active, tok, st["tokens"])
-            remaining = jnp.where(active, st["remaining"] - 1,
-                                  st["remaining"])
-            hit_eos = active & (st["eos"] >= 0) & (tok == st["eos"])
-            st = {
-                "tokens": tok,
-                "index": jnp.where(active, st["index"] + 1, st["index"]),
-                "key": key,
-                "temperature": st["temperature"],
-                "eos": st["eos"],
-                "remaining": remaining,
-                "active": active & (remaining > 0) & ~hit_eos,
-                "spec_limit": st["spec_limit"],
-            }
+            with scope("sample"):
+                key, sub = jax.random.split(st["key"])
+                tok = T.sample_tokens(logits[:, -1], sub, st["temperature"])
+                active = st["active"]
+                tok = jnp.where(active, tok, st["tokens"])
+                remaining = jnp.where(active, st["remaining"] - 1,
+                                      st["remaining"])
+                hit_eos = active & (st["eos"] >= 0) & (tok == st["eos"])
+                st = {
+                    "tokens": tok,
+                    "index": jnp.where(active, st["index"] + 1, st["index"]),
+                    "key": key,
+                    "temperature": st["temperature"],
+                    "eos": st["eos"],
+                    "remaining": remaining,
+                    "active": active & (remaining > 0) & ~hit_eos,
+                    "spec_limit": st["spec_limit"],
+                }
             carry = (caches, st, led) if ledger is not None else (caches, st)
             return carry, tok
 
         if ledger is not None:
-            (caches, state, led), tok_block = jax.lax.scan(
-                micro, (caches, state, ledger_in), None, length=n_steps)
+            with scope("decode_loop"):
+                (caches, state, led), tok_block = jax.lax.scan(
+                    micro, (caches, state, ledger_in), None, length=n_steps)
             return tok_block, caches, state, led
-        (caches, state), tok_block = jax.lax.scan(
-            micro, (caches, state), None, length=n_steps)
+        with scope("decode_loop"):
+            (caches, state), tok_block = jax.lax.scan(
+                micro, (caches, state), None, length=n_steps)
         return tok_block, caches, state
 
     if pages_meta is not None:

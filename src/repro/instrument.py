@@ -1,4 +1,4 @@
-"""Trace-time event-counter registry with scoped reset.
+"""Trace-time instrumentation: event-counter registry and scope contract.
 
 Several modules record *trace-time* evidence that a particular code path
 actually compiled — `kernels.pallas_compat.SKINNY_M_EVENTS` (a GEMM padded
@@ -27,13 +27,43 @@ objects**. What the registry adds:
 In-place mutation (never rebinding) is the load-bearing detail: aliases in
 other modules (`ops.SKINNY_M_EVENTS`, `from ... import GATHER_EVENTS`)
 stay live because the identity of each list never changes.
+
+The scope contract names the layers of a compiled step inside the program
+itself. `scope(name)` is a `jax.named_scope` over one of `SCOPES`; the
+name lands in the op metadata (`op_name`) of every op traced under it, which
+a profiler capture carries as the op's `tf_op` stat. An op belongs to the
+innermost contract name on its path (`bench/opscope.py` reads them):
+
+  * `attn_core`   scores, mask, softmax, P.V, and the layout of K/V for
+                  them: head split/merge, broadcast of K/V to the query
+                  heads, the paged view of a cache, the flash kernel;
+  * `kv_write`    writing new K/V (or MLA latents) into a cache, and
+                  making a cache;
+  * `layer_scan`  the `lax.scan` over layers: its per-layer slices of the
+                  stacked weights and cache (xs) and their write-back (ys);
+  * `decode_loop` the micro-step `lax.scan` of the fused decode dispatch:
+                  its carry copies;
+  * `gemm`        every Kratos projection and the LM head, with the
+                  projection's name below it (`gemm/wq`, `gemm/head`);
+  * `norm`        norms and the residual adds around them;
+  * `rope`        rotary embedding and the positions it reads;
+  * `mlp_act`     the MLP's activation and gating product;
+  * `embed`       the token embedding;
+  * `sample`      on-device sampling and the decode loop's per-slot
+                  bookkeeping.
+
+Scopes touch only op metadata: the compiled program is the same with or
+without them (tests/test_instrument.py compares the two).
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
-from typing import Dict, Iterator, List, Tuple
+from typing import Callable, Dict, Iterator, List, Tuple
+
+import jax
 
 
 class EventList(list):
@@ -100,3 +130,38 @@ class EventRegistry:
 
 
 REGISTRY = EventRegistry()
+
+
+# ---------------------------------------------------------------------------
+# Scope contract (device side)
+# ---------------------------------------------------------------------------
+
+SCOPES: Tuple[str, ...] = ("attn_core", "kv_write", "layer_scan",
+                           "decode_loop", "gemm", "norm", "rope", "mlp_act",
+                           "embed", "sample")
+
+
+@contextlib.contextmanager
+def scope(name: str, *below: str) -> Iterator[None]:
+    """Name the ops traced in the body after contract scope `name`, with
+    the non-empty names of `below` (e.g. a projection's name) nested under
+    it."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not a contract scope: {SCOPES}")
+    with contextlib.ExitStack() as stack:
+        for n in (name,) + below:
+            if n:
+                stack.enter_context(jax.named_scope(n))
+        yield
+
+
+def scoped(name: str) -> Callable[[Callable], Callable]:
+    """Decorator form of `scope`: every call runs under contract scope
+    `name`."""
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap

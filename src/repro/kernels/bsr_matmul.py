@@ -84,6 +84,7 @@ def bsr_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n_pb * bn), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="bsr_matmul",
         interpret=interpret,
     )(jnp.asarray(indices, jnp.int32), x, blocks)
     return out if m == m_orig else out[:m_orig]

@@ -70,6 +70,7 @@ def dense_matmul(
         out_shape=jax.ShapeDtypeStruct((m, p), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="dense_matmul",
         interpret=interpret,
     )(x, w)
     return out if m == m_orig else out[:m_orig]

@@ -132,6 +132,7 @@ def flash_attention(
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
 
@@ -271,6 +272,7 @@ def paged_flash_attention(
         out_shape=jax.ShapeDtypeStruct((b, h, s_pad, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(table.astype(jnp.int32), last.astype(jnp.int32), q, k_pages, v_pages)
     return out[:, :, :sq] if s_pad != sq else out

@@ -112,6 +112,7 @@ def quant_matmul(
         out_shape=jax.ShapeDtypeStruct((m, p), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="quant_matmul",
         interpret=interpret,
     )(x, qt.data, scale2d)
     return out if m == m_orig else out[:m_orig]
@@ -177,6 +178,7 @@ def quant_matmul_w8a8(
         out_shape=jax.ShapeDtypeStruct((m, p), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="quant_matmul_w8a8",
         interpret=interpret,
     )(xq, xs, qt.data, qt.scale.reshape(1, p))
     return out if m == m_orig else out[:m_orig]
@@ -248,6 +250,7 @@ def bsr_quant_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n_pb * bn), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="bsr_quant_matmul",
         interpret=interpret,
     )(jnp.asarray(indices, jnp.int32), x, qblocks,
       scales.reshape(1, n_pb * bn))

@@ -102,6 +102,7 @@ def ssm_scan(
         scratch_shapes=[pltpu.VMEM((st, bd), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="ssm_scan",
         interpret=interpret,
     )(u, dt, b, c, a)
     return y, h

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kratos as kr
+from repro.instrument import scope, scoped
 from repro.kernels import ops
 from repro.kernels import ref as kref
 from repro.models import layers as L
@@ -86,6 +87,7 @@ class AttnConfig:
 # Core masked attention (positions-aware; handles circular caches)
 # ---------------------------------------------------------------------------
 
+@scoped("attn_core")
 def attention_positional(q, k, v, q_pos, kv_pos, *, causal=True, window=None,
                          softcap=None, scale=None, extra_mask=None):
     """q: (B,H,Sq,Dk); k: (B,KV,Skv,Dk); v: (B,KV,Skv,Dv); GQA via reshape.
@@ -145,6 +147,7 @@ CHUNKED_ATTN_THRESHOLD = 4096
 CHUNK_Q = 1024
 
 
+@scoped("attn_core")
 def attention_chunked(q, k, v, q_pos, kv_pos, *, causal=True, window=None,
                       softcap=None, scale=None, extra_mask=None,
                       chunk: int = CHUNK_Q):
@@ -175,6 +178,7 @@ def attention_chunked(q, k, v, q_pos, kv_pos, *, causal=True, window=None,
     return out
 
 
+@scoped("attn_core")
 def _sdpa(q, k, v, cfg: AttnConfig, *, q_pos, kv_pos, extra_mask=None,
           backend="ref", contiguous=False, q_offset=0):
     """Dispatch: flash kernel for contiguous full-seq, positional math otherwise."""
@@ -212,6 +216,7 @@ def gqa_init(key, cfg: AttnConfig, spec: kr.KratosSpec = kr.DENSE,
     return p
 
 
+@scoped("rope")
 def _positions_for(index, s: int) -> jnp.ndarray:
     """Absolute positions for a length-s segment starting at `index`.
 
@@ -261,11 +266,13 @@ def _page_offsets(pages, index, b: int, s: int):
     return page, pos % psize, idx + (s - 1)
 
 
+@scoped("attn_core")
 def _split_heads(x, n, dh):
     b, s, _ = x.shape
     return x.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
 
 
+@scoped("attn_core")
 def _merge_heads(x):
     b, h, s, dh = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
@@ -289,7 +296,8 @@ def gqa_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
     """
     b, s, d = x.shape
     h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = _split_heads(kr.apply(params["wq"], x, spec, backend=backend), h, dh)
+    q = _split_heads(kr.apply(params["wq"], x, spec, backend=backend,
+                              name="wq"), h, dh)
 
     if cfg.cross:
         # cross-attention (whisper decoder): k/v from the encoder, cached at
@@ -298,23 +306,27 @@ def gqa_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
             k, v, new_cache = cache["k"], cache["v"], cache
         else:
             k = _split_heads(kr.apply(params["wk"], kv_source, spec,
-                                      backend=backend), kv, dh)
+                                      backend=backend, name="wk"), kv, dh)
             v = _split_heads(kr.apply(params["wv"], kv_source, spec,
-                                      backend=backend), kv, dh)
+                                      backend=backend, name="wv"), kv, dh)
             new_cache = {"k": k, "v": v}
         skv = k.shape[2]
-        o = attention_positional(
-            q, k.astype(x.dtype), v.astype(x.dtype), jnp.arange(s),
-            jnp.arange(skv), causal=False, softcap=cfg.softcap, scale=cfg.scale)
+        with scope("attn_core"):
+            o = attention_positional(
+                q, k.astype(x.dtype), v.astype(x.dtype), jnp.arange(s),
+                jnp.arange(skv), causal=False, softcap=cfg.softcap,
+                scale=cfg.scale)
         mo = _merge_heads(o)
         if probe is not None:
             probe.tap(mo, cfg.d_model)
-        y = kr.apply(params["wo"], mo, spec, backend=backend)
+        y = kr.apply(params["wo"], mo, spec, backend=backend, name="wo")
         return y, new_cache
 
     kv_in = x if kv_source is None else kv_source
-    k = _split_heads(kr.apply(params["wk"], kv_in, spec, backend=backend), kv, dh)
-    v = _split_heads(kr.apply(params["wv"], kv_in, spec, backend=backend), kv, dh)
+    k = _split_heads(kr.apply(params["wk"], kv_in, spec, backend=backend,
+                              name="wk"), kv, dh)
+    v = _split_heads(kr.apply(params["wv"], kv_in, spec, backend=backend,
+                              name="wv"), kv, dh)
     if cfg.qk_norm:
         q = L.rmsnorm(params["q_norm"], q)
         k = L.rmsnorm(params["k_norm"], k)
@@ -345,14 +357,16 @@ def gqa_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
     else:
         # decode: write k/v at index (circular for windowed layers), attend
         new_cache, kv_pos, valid = _decode_cache_write(cache, k, v, cfg, index)
-        o = attention_positional(
-            q, new_cache["k"].astype(x.dtype), new_cache["v"].astype(x.dtype),
-            positions, kv_pos, causal=cfg.causal, window=cfg.window,
-            softcap=cfg.softcap, extra_mask=valid, scale=cfg.scale)
+        with scope("attn_core"):        # the cache's cast for the read too
+            o = attention_positional(
+                q, new_cache["k"].astype(x.dtype),
+                new_cache["v"].astype(x.dtype), positions, kv_pos,
+                causal=cfg.causal, window=cfg.window, softcap=cfg.softcap,
+                extra_mask=valid, scale=cfg.scale)
     mo = _merge_heads(o)
     if probe is not None:
         probe.tap(mo, cfg.d_model)
-    y = kr.apply(params["wo"], mo, spec, backend=backend)
+    y = kr.apply(params["wo"], mo, spec, backend=backend, name="wo")
     y = L.shard(y, "batch", None, "dm_in")   # see layers.mlp_apply note
     return y, new_cache
 
@@ -363,6 +377,7 @@ def make_gqa_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.bfloat16
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@scoped("kv_write")
 def _prefill_cache(cache, k, v, cfg: AttnConfig):
     """Fill cache from a contiguous prefill of length s (s <= cache size or,
     for windowed layers, keep the last W positions in circular layout)."""
@@ -382,6 +397,7 @@ def _prefill_cache(cache, k, v, cfg: AttnConfig):
     }
 
 
+@scoped("kv_write")
 def _decode_cache_write(cache, k, v, cfg: AttnConfig, index):
     """Write s token(s) at `index`..; return (cache, kv_positions, valid).
 
@@ -448,28 +464,32 @@ def _paged_gqa_decode(q, k, v, cfg: AttnConfig, cache, index, pages,
     contiguous view and runs the exact slab attention program (bit-identity
     with the slab decode branch by construction)."""
     b, s = q.shape[0], q.shape[2]
-    page, off, last = _page_offsets(pages, index, b, s)
-    k, v = jax.lax.optimization_barrier(
-        (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
-    # advanced indices (B,s) at axes 0/2 with a sliced head axis between
-    # them move to the front: the update operand is (B, s, KV, dh).
-    ck = cache["k"].at[page, :, off, :].set(k.transpose(0, 2, 1, 3))
-    cv = cache["v"].at[page, :, off, :].set(v.transpose(0, 2, 1, 3))
+    with scope("kv_write"):
+        page, off, last = _page_offsets(pages, index, b, s)
+        k, v = jax.lax.optimization_barrier(
+            (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
+        # advanced indices (B,s) at axes 0/2 with a sliced head axis
+        # between them move to the front: the update operand is
+        # (B, s, KV, dh).
+        ck = cache["k"].at[page, :, off, :].set(k.transpose(0, 2, 1, 3))
+        cv = cache["v"].at[page, :, off, :].set(v.transpose(0, 2, 1, 3))
     new_cache = {"k": ck, "v": cv}
-    if backend in ("pallas", "interpret") and q.shape[-1] == v.shape[-1]:
-        o = ops.paged_attention(
-            q, ck, cv, pages["table"], last, window=cfg.window,
-            softcap=cfg.softcap, scale=cfg.scale, backend=backend)
-        return new_cache, o.astype(q.dtype)
-    ops.PAGED_ATTN_EVENTS.append(("ref", b, pages["table"].shape[1]))
-    k_view = _paged_leaf_view(ck, pages["table"], pages["len"])
-    v_view = _paged_leaf_view(cv, pages["table"], pages["len"])
-    slots = jnp.arange(pages["len"])
-    valid = slots[None] <= last[:, None]
-    o = attention_positional(
-        q, k_view.astype(out_dtype), v_view.astype(out_dtype),
-        positions, slots, causal=cfg.causal, window=cfg.window,
-        softcap=cfg.softcap, extra_mask=valid, scale=cfg.scale)
+    with scope("attn_core"):
+        if backend in ("pallas", "interpret") \
+                and q.shape[-1] == v.shape[-1]:
+            o = ops.paged_attention(
+                q, ck, cv, pages["table"], last, window=cfg.window,
+                softcap=cfg.softcap, scale=cfg.scale, backend=backend)
+            return new_cache, o.astype(q.dtype)
+        ops.PAGED_ATTN_EVENTS.append(("ref", b, pages["table"].shape[1]))
+        k_view = _paged_leaf_view(ck, pages["table"], pages["len"])
+        v_view = _paged_leaf_view(cv, pages["table"], pages["len"])
+        slots = jnp.arange(pages["len"])
+        valid = slots[None] <= last[:, None]
+        o = attention_positional(
+            q, k_view.astype(out_dtype), v_view.astype(out_dtype),
+            positions, slots, causal=cfg.causal, window=cfg.window,
+            softcap=cfg.softcap, extra_mask=valid, scale=cfg.scale)
     return new_cache, o
 
 
@@ -501,11 +521,11 @@ def _mla_q(params, x, cfg, spec, backend):
     h = cfg.n_heads
     qd = cfg.qk_nope_dim + cfg.qk_rope_dim
     if cfg.q_lora_rank:
-        qa = kr.apply(params["wq_a"], x, spec, backend=backend)
+        qa = kr.apply(params["wq_a"], x, spec, backend=backend, name="wq_a")
         q = kr.apply(params["wq_b"], L.rmsnorm(params["q_norm"], qa), spec,
-                     backend=backend)
+                     backend=backend, name="wq_b")
     else:
-        q = kr.apply(params["wq"], x, spec, backend=backend)
+        q = kr.apply(params["wq"], x, spec, backend=backend, name="wq")
     q = _split_heads(q, h, qd)
     return q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
 
@@ -513,7 +533,8 @@ def _mla_q(params, x, cfg, spec, backend):
 def _mla_expand_kv(params, c_kv, cfg, spec, backend):
     """(B, S, r) latent -> k_nope (B,H,S,nope), v (B,H,S,vd)."""
     h = cfg.n_heads
-    kvb = kr.apply(params["wkv_b"], c_kv, spec, backend=backend)
+    kvb = kr.apply(params["wkv_b"], c_kv, spec, backend=backend,
+                   name="wkv_b")
     kvb = _split_heads(kvb, h, cfg.qk_nope_dim + cfg.v_head_dim)
     return kvb[..., :cfg.qk_nope_dim], kvb[..., cfg.qk_nope_dim:]
 
@@ -529,85 +550,94 @@ def mla_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
 
     q_nope, q_rope = _mla_q(params, x, cfg, spec, backend)
     q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    with scope("attn_core"):
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
 
-    kv_a = kr.apply(params["wkv_a"], x, spec, backend=backend)
+    kv_a = kr.apply(params["wkv_a"], x, spec, backend=backend, name="wkv_a")
     c_kv = L.rmsnorm(params["kv_norm"], kv_a[..., :cfg.kv_lora_rank])
-    k_rope = kv_a[..., cfg.kv_lora_rank:][:, :, None]          # (B,S,1,dr)
-    k_rope = L.apply_rope(k_rope.transpose(0, 2, 1, 3), positions,
-                          cfg.rope_theta)                      # (B,1,S,dr)
+    with scope("rope"):
+        k_rope = kv_a[..., cfg.kv_lora_rank:][:, :, None]      # (B,S,1,dr)
+        k_rope = L.apply_rope(k_rope.transpose(0, 2, 1, 3), positions,
+                              cfg.rope_theta)                  # (B,1,S,dr)
 
     new_cache = None
-    if cache is not None and index is not None and pages is not None:
-        # NATIVE paged decode: append latents straight into their pages
-        # (in-place page-indexed scatter), read the sliced table view —
-        # value-identical to the slab rows, so the expand below compiles
-        # to the exact slab program. MLA stays on the XLA view path (the
-        # Pallas paged kernel is GQA-shaped: dk != dv and the latent
-        # expansion happens outside the kernel).
-        ops.PAGED_ATTN_EVENTS.append(("mla", b, pages["table"].shape[1]))
-        c_upd, r_upd = jax.lax.optimization_barrier(
-            (c_kv.astype(cache["c_kv"].dtype),
-             k_rope.astype(cache["k_rope"].dtype)))
-        page, off, last = _page_offsets(pages, index, b, c_upd.shape[1])
-        ck = cache["c_kv"].at[page, off, :].set(c_upd)
-        cr = cache["k_rope"].at[page, :, off, :].set(
-            r_upd.transpose(0, 2, 1, 3))
-        new_cache = {"c_kv": ck, "k_rope": cr}
-        c_all = _paged_leaf_view(ck, pages["table"], pages["len"])
-        kr_all = _paged_leaf_view(cr, pages["table"], pages["len"])
-        kv_pos = jnp.arange(pages["len"])
-        valid = kv_pos[None] <= last[:, None]
-    elif cache is not None and index is not None:
-        # decode: append compressed latents, expand the whole cache (naive MLA)
-        c_upd, r_upd = jax.lax.optimization_barrier(
-            (c_kv.astype(cache["c_kv"].dtype),
-             k_rope.astype(cache["k_rope"].dtype)))  # see _decode_cache_write
-        last = index + (c_upd.shape[1] - 1)   # s > 1: speculative block write
-        if jnp.ndim(index) == 0:
-            ck = jax.lax.dynamic_update_slice(
-                cache["c_kv"], c_upd, (0, index, 0))
-            cr = jax.lax.dynamic_update_slice(
-                cache["k_rope"], r_upd, (0, 0, index, 0))
-            kv_pos = jnp.arange(ck.shape[1])
-            valid = kv_pos <= last
-        else:                      # per-slot clocks (continuous batching)
-            ck = jax.vmap(
-                lambda c, u, at: jax.lax.dynamic_update_slice(c, u, (at, 0)))(
-                cache["c_kv"], c_upd, index)
-            cr = jax.vmap(
-                lambda c, u, at: jax.lax.dynamic_update_slice(c, u, (0, at, 0)))(
-                cache["k_rope"], r_upd, index)
-            kv_pos = jnp.arange(ck.shape[1])
+    with scope("kv_write"):
+        if cache is not None and index is not None and pages is not None:
+            # NATIVE paged decode: append latents straight into their pages
+            # (in-place page-indexed scatter), read the sliced table view —
+            # value-identical to the slab rows, so the expand below compiles
+            # to the exact slab program. MLA stays on the XLA view path (the
+            # Pallas paged kernel is GQA-shaped: dk != dv and the latent
+            # expansion happens outside the kernel).
+            ops.PAGED_ATTN_EVENTS.append(("mla", b, pages["table"].shape[1]))
+            c_upd, r_upd = jax.lax.optimization_barrier(
+                (c_kv.astype(cache["c_kv"].dtype),
+                 k_rope.astype(cache["k_rope"].dtype)))
+            page, off, last = _page_offsets(pages, index, b, c_upd.shape[1])
+            ck = cache["c_kv"].at[page, off, :].set(c_upd)
+            cr = cache["k_rope"].at[page, :, off, :].set(
+                r_upd.transpose(0, 2, 1, 3))
+            new_cache = {"c_kv": ck, "k_rope": cr}
+            with scope("attn_core"):
+                c_all = _paged_leaf_view(ck, pages["table"], pages["len"])
+                kr_all = _paged_leaf_view(cr, pages["table"], pages["len"])
+            kv_pos = jnp.arange(pages["len"])
             valid = kv_pos[None] <= last[:, None]
-        new_cache = {"c_kv": ck, "k_rope": cr}
-        c_all, kr_all = ck, cr
-    elif cache is not None:
-        ck = jax.lax.dynamic_update_slice(
-            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, 0, 0))
-        cr = jax.lax.dynamic_update_slice(
-            cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), (0, 0, 0, 0))
-        new_cache = {"c_kv": ck, "k_rope": cr}
-        c_all, kr_all = c_kv, k_rope
-        kv_pos, valid = positions, None
-    else:
-        c_all, kr_all = c_kv, k_rope
-        kv_pos, valid = positions, None
+        elif cache is not None and index is not None:
+            # decode: append compressed latents, expand the whole cache
+            # (naive MLA)
+            # the barrier: see _decode_cache_write
+            c_upd, r_upd = jax.lax.optimization_barrier(
+                (c_kv.astype(cache["c_kv"].dtype),
+                 k_rope.astype(cache["k_rope"].dtype)))
+            # s > 1: speculative block write
+            last = index + (c_upd.shape[1] - 1)
+            if jnp.ndim(index) == 0:
+                ck = jax.lax.dynamic_update_slice(
+                    cache["c_kv"], c_upd, (0, index, 0))
+                cr = jax.lax.dynamic_update_slice(
+                    cache["k_rope"], r_upd, (0, 0, index, 0))
+                kv_pos = jnp.arange(ck.shape[1])
+                valid = kv_pos <= last
+            else:                  # per-slot clocks (continuous batching)
+                ck = jax.vmap(lambda c, u, at: jax.lax.dynamic_update_slice(
+                    c, u, (at, 0)))(cache["c_kv"], c_upd, index)
+                cr = jax.vmap(lambda c, u, at: jax.lax.dynamic_update_slice(
+                    c, u, (0, at, 0)))(cache["k_rope"], r_upd, index)
+                kv_pos = jnp.arange(ck.shape[1])
+                valid = kv_pos[None] <= last[:, None]
+            new_cache = {"c_kv": ck, "k_rope": cr}
+            c_all, kr_all = ck, cr
+        elif cache is not None:
+            ck = jax.lax.dynamic_update_slice(
+                cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, 0, 0))
+            cr = jax.lax.dynamic_update_slice(
+                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
+                (0, 0, 0, 0))
+            new_cache = {"c_kv": ck, "k_rope": cr}
+            c_all, kr_all = c_kv, k_rope
+            kv_pos, valid = positions, None
+        else:
+            c_all, kr_all = c_kv, k_rope
+            kv_pos, valid = positions, None
 
-    k_nope, v = _mla_expand_kv(params, c_all.astype(x.dtype), cfg, spec, backend)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(kr_all.astype(x.dtype),
-                                  (b, h, k_nope.shape[2], cfg.qk_rope_dim))],
-        axis=-1)
-    attn_fn = attention_chunked if s > CHUNKED_ATTN_THRESHOLD \
-        else attention_positional
-    o = attn_fn(
-        q, k, v, positions, kv_pos, causal=cfg.causal, window=cfg.window,
-        softcap=cfg.softcap, extra_mask=valid, scale=cfg.scale)
+    with scope("attn_core"):
+        k_nope, v = _mla_expand_kv(params, c_all.astype(x.dtype), cfg, spec,
+                                   backend)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(kr_all.astype(x.dtype),
+                                      (b, h, k_nope.shape[2],
+                                       cfg.qk_rope_dim))],
+            axis=-1)
+        attn_fn = attention_chunked if s > CHUNKED_ATTN_THRESHOLD \
+            else attention_positional
+        o = attn_fn(
+            q, k, v, positions, kv_pos, causal=cfg.causal, window=cfg.window,
+            softcap=cfg.softcap, extra_mask=valid, scale=cfg.scale)
     mo = _merge_heads(o)
     if probe is not None:
         probe.tap(mo, cfg.d_model)
-    y = kr.apply(params["wo"], mo, spec, backend=backend)
+    y = kr.apply(params["wo"], mo, spec, backend=backend, name="wo")
     y = L.shard(y, "batch", None, "dm_in")   # see layers.mlp_apply note
     return y, new_cache
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kratos as kr
+from repro.instrument import scope, scoped
 
 
 # ---------------------------------------------------------------------------
@@ -44,6 +45,7 @@ def rmsnorm_init(d: int, dtype=jnp.float32) -> Dict:
     return {"scale": jnp.ones((d,), dtype)}
 
 
+@scoped("norm")
 def rmsnorm(params: Dict, x: jnp.ndarray, eps: float = 1e-6,
             scale_plus_one: bool = False) -> jnp.ndarray:
     h = x.astype(jnp.float32)
@@ -59,6 +61,7 @@ def layernorm_init(d: int, dtype=jnp.float32) -> Dict:
     return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
 
 
+@scoped("norm")
 def layernorm(params: Dict, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     h = x.astype(jnp.float32)
     mu = jnp.mean(h, axis=-1, keepdims=True)
@@ -76,6 +79,7 @@ def rope_freqs(head_dim: int, theta: float = 10000.0) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+@scoped("rope")
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
                theta: float = 10000.0) -> jnp.ndarray:
     """x: (B, H, S, Dh) (Dh even); positions: (S,) or (B, S) absolute."""
@@ -124,19 +128,22 @@ def mlp_apply(params: Dict, x: jnp.ndarray, *, activation: str = "silu",
               spec: kr.KratosSpec = kr.DENSE, backend: str = "ref",
               probe=None) -> jnp.ndarray:
     act = ACTIVATIONS[activation]
-    up = kr.apply(params["w_up"], x, spec, backend=backend)
+    up = kr.apply(params["w_up"], x, spec, backend=backend, name="w_up")
     if "w_gate" in params:
-        gate = kr.apply(params["w_gate"], x, spec, backend=backend)
-        h = act(gate) * up
+        gate = kr.apply(params["w_gate"], x, spec, backend=backend,
+                        name="w_gate")
+        with scope("mlp_act"):
+            h = act(gate) * up
     else:
-        h = act(up)
+        with scope("mlp_act"):
+            h = act(up)
     h = shard(h, "batch", "seq", "ffn")
     if probe is not None:
         # the activation-sparsity site: ReLU-family nonlinearities zero a
         # large fraction of h, and every zero row-element makes its w_down
         # k-slice ineffectual (serve.ledger)
         probe.tap(h, x.shape[-1])
-    y = kr.apply(params["w_down"], h, spec, backend=backend)
+    y = kr.apply(params["w_down"], h, spec, backend=backend, name="w_down")
     # pin the row-parallel product to batch-sharded rows: without this,
     # GSPMD may satisfy the weight's FSDP out-dim by all-gathering the
     # batch over 'data' (a 4.5 GiB/layer intermediate on nemotron-340b).
@@ -151,6 +158,7 @@ def embedding_init(key, vocab: int, d: int, dtype=jnp.float32) -> Dict:
     return {"emb": jax.random.normal(key, (vocab, d), dtype) * 0.02}
 
 
+@scoped("embed")
 def embed(params: Dict, tokens: jnp.ndarray, *, scale: float = 1.0) -> jnp.ndarray:
     out = jnp.take(params["emb"], tokens, axis=0)
     if scale != 1.0:
@@ -161,10 +169,11 @@ def embed(params: Dict, tokens: jnp.ndarray, *, scale: float = 1.0) -> jnp.ndarr
 def unembed(params: Dict, x: jnp.ndarray, head: Optional[Dict] = None,
             *, softcap: Optional[float] = None) -> jnp.ndarray:
     from repro.kernels import ref as kref   # accum-dtype switch (see ref.py)
-    w = head["w"] if head is not None else params["emb"].T
-    logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype),
-                        preferred_element_type=kref._DOT_ACCUM)
-    logits = logits.astype(jnp.float32)
-    if softcap is not None:
-        logits = softcap * jnp.tanh(logits / softcap)
+    with scope("gemm", "head"):
+        w = head["w"] if head is not None else params["emb"].T
+        logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype),
+                            preferred_element_type=kref._DOT_ACCUM)
+        logits = logits.astype(jnp.float32)
+        if softcap is not None:
+            logits = softcap * jnp.tanh(logits / softcap)
     return shard(logits, "batch", "seq", "vocab")

@@ -87,10 +87,12 @@ def _expert_ffn(p, xe: jnp.ndarray, cfg: MoEConfig, spec: kr.KratosSpec,
 
     # tree path: vmap the Kratos gathered-block matmul over experts
     def one(we_gate, we_up, we_down, xx):      # xx: (G, C, d)
-        g = kr.apply({"w": we_gate}, xx, spec, backend=backend)
-        u = kr.apply({"w": we_up}, xx, spec, backend=backend)
+        g = kr.apply({"w": we_gate}, xx, spec, backend=backend,
+                     name="w_gate")
+        u = kr.apply({"w": we_up}, xx, spec, backend=backend, name="w_up")
         h = act(g) * u
-        return kr.apply({"w": we_down}, h, spec, backend=backend)
+        return kr.apply({"w": we_down}, h, spec, backend=backend,
+                        name="w_down")
 
     out = jax.vmap(one, in_axes=(0, 0, 0, 1), out_axes=1)(
         p["w_gate"], p["w_up"], p["w_down"], xe)

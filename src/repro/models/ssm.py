@@ -84,7 +84,8 @@ def _depthwise_conv(u: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
 def _ssm_params(params, u, cfg: MambaConfig, spec, backend):
     """u: (B, S, di) -> dt (B,S,di), B_ (B,S,st), C_ (B,S,st)."""
     st, r = cfg.d_state, cfg.rank
-    xdbc = kr.apply(params["x_proj"], u, spec, backend=backend)
+    xdbc = kr.apply(params["x_proj"], u, spec, backend=backend,
+                    name="x_proj")
     dt_in, b_, c_ = jnp.split(xdbc, [r, r + st], axis=-1)
     if cfg.bcdt_rms:
         dt_in = L.rmsnorm(params["dt_norm"], dt_in)
@@ -134,7 +135,8 @@ def mamba_apply(params, x, cfg: MambaConfig, *, spec=kr.DENSE, backend="ref",
     """x: (B, S, d). cache: {'conv': (B,K-1,di), 'ssm': (B,di,st)} for decode."""
     b, s, d = x.shape
     di, st = cfg.d_inner, cfg.d_state
-    ug = kr.apply(params["in_proj"], x, spec, backend=backend)
+    ug = kr.apply(params["in_proj"], x, spec, backend=backend,
+                    name="in_proj")
     u, gate = jnp.split(ug, 2, axis=-1)                     # (B,S,di) each
 
     decode = cache is not None and index is not None
@@ -187,7 +189,8 @@ def mamba_apply(params, x, cfg: MambaConfig, *, spec=kr.DENSE, backend="ref",
                          "ssm": h[:, -1].astype(cache["ssm"].dtype)}
     y = y.astype(x.dtype) + u_act * params["D"].astype(x.dtype)
     y = y * jax.nn.silu(gate)
-    out = kr.apply(params["out_proj"], y, spec, backend=backend)
+    out = kr.apply(params["out_proj"], y, spec, backend=backend,
+                    name="out_proj")
     out = L.shard(out, "batch", None, "dm_in")   # see layers.mlp_apply note
     return out, new_cache
 

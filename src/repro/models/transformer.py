@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kratos as kr
+from repro.instrument import scope, scoped
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
@@ -290,6 +291,12 @@ def _probe_fanout(cfg: ModelConfig, kind: Dict, site: str) -> int:
     return (2 if gated else 1) * cfg.d_ff
 
 
+@scoped("norm")
+def _residual(x, h, rs):
+    """The residual stream's add, named with the norms it feeds."""
+    return x + h * rs
+
+
 def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
                  positions=None, cache=None, index=None, enc_out=None,
                  cross_cache=None, pages=None, probe=None):
@@ -324,7 +331,7 @@ def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
             backend=backend, cache=cache, index=index)
     if cfg.sandwich_norm:
         h = _norm(cfg, p["post_norm"], h)
-    x = x + h * rs
+    x = _residual(x, h, rs)
     if "cross" in p:
         h = L.shard(_norm(cfg, p["cross_norm"], x), "batch", None, "dm_in")
         ccfg = dataclasses.replace(attn_cfg_for(cfg, kind), cross=True,
@@ -332,7 +339,7 @@ def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
         h, new_cross = A.attn_apply(
             p["cross"], h, ccfg, spec=cfg.kratos, backend=backend,
             kv_source=enc_out, cache=cross_cache, index=index, probe=probe)
-        x = x + h * rs
+        x = _residual(x, h, rs)
     if kind["ffn"] != "none":
         h = L.shard(_norm(cfg, p["ffn_norm"], x), "batch", None, "dm_in")
         if probe is not None:
@@ -345,13 +352,15 @@ def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
                             spec=cfg.kratos, backend=backend, probe=probe)
         if cfg.sandwich_norm:
             h = _norm(cfg, p["ffn_post_norm"], h)
-        x = x + h * rs
+        x = _residual(x, h, rs)
     # 'seq_res' = sequence-sharded residual stream (SP): the remat carry that
     # lives across the whole layer scan is sharded over the 'model' axis, which
     # is what fits 96-layer x 4k-seq saved activations in 16 GB/chip. The cast
     # keeps the carry in the activation dtype — mixed-precision dots upcast to
     # f32 and the saved stack must not inherit that (2x remat memory).
-    x = L.shard(x.astype(cfg.adtype()), "batch", "seq_res", None)
+    with scope("norm"):
+        x = x.astype(cfg.adtype())
+    x = L.shard(x, "batch", "seq_res", None)
     return x, aux, new_cache, new_cross
 
 
@@ -496,7 +505,9 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, *, backend="ref",
     Layer order is TRUE model order: scanned slot s, period t is layer
     `prelude + t * scan_period + s`.
     """
-    x = L.embed(params["embed"], tokens, scale=cfg.emb_scale).astype(cfg.adtype())
+    with scope("embed"):
+        x = L.embed(params["embed"], tokens,
+                    scale=cfg.emb_scale).astype(cfg.adtype())
     if img_embeds is not None:
         x = jnp.concatenate([img_embeds.astype(x.dtype), x], axis=1)
     if cfg.enc_dec and not cfg.use_rope:
@@ -566,17 +577,19 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, *, backend="ref",
             return (x, aux + a), out
 
         xs = (stacked, c_stack) if caches is not None else stacked
-        (x, aux_total), new_stack = jax.lax.scan(
-            _remat_wrap(cfg, body), (x, aux_total), xs)
+        with scope("layer_scan"):
+            (x, aux_total), new_stack = jax.lax.scan(
+                _remat_wrap(cfg, body), (x, aux_total), xs)
         if probe is not None:
             new_stack, rows = new_stack          # (n_periods, width)
             slot_rows.append(rows)
         if caches is not None:
             new_caches["blocks"][slot] = new_stack
 
-    if last_only:
-        x = x[:, -1:]
-    x = _norm(cfg, params["final_norm"], x)
+    with scope("norm"):
+        if last_only:
+            x = x[:, -1:]
+        x = _norm(cfg, params["final_norm"], x)
     x = L.shard(x, "batch", "seq", None)
     logits = L.unembed(params["embed"], x, params.get("head"),
                        softcap=cfg.logit_softcap)
@@ -628,6 +641,7 @@ def make_caches(cfg: ModelConfig, batch: int, max_len: int,
 # Sampling (device-side; fused into the serving decode step)
 # ---------------------------------------------------------------------------
 
+@scoped("sample")
 def sample_tokens(logits: jnp.ndarray, key, temperature: jnp.ndarray,
                   ) -> jnp.ndarray:
     """Per-row Gumbel-max / greedy sampling, fully on device.

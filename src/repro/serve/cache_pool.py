@@ -38,6 +38,7 @@ from typing import Any, Dict, List
 import jax
 import jax.numpy as jnp
 
+from repro.instrument import scoped
 from repro.models import transformer as T
 from repro.serve.trace import NULL_TRACER
 
@@ -64,6 +65,7 @@ class PoolExhausted(RuntimeError):
     """No free cache slot: the scheduler must hold the request in the queue."""
 
 
+@scoped("kv_write")
 def _write_tree(slab: Dict, single: Dict, slot) -> Dict:
     """Write a batch-1 cache tree into row `slot` of the slab (functional)."""
     pre = jax.tree_util.tree_map(

@@ -138,7 +138,8 @@ from repro.serve.metrics import ServeMetrics
 from repro.serve.registry import PackedModel
 from repro.serve.scheduler import (ContinuousScheduler, Request,
                                    SchedulerBase)
-from repro.serve.trace import NULL_TRACER, TraceConfig, Tracer
+from repro.serve.trace import (NULL_TRACER, TraceConfig, Tracer, span,
+                               spanned)
 
 
 class EngineSaturated(RuntimeError):
@@ -505,6 +506,7 @@ class InferenceEngine:
     def n_waiting(self) -> int:
         return len(self._waiting)
 
+    @spanned("serve.step")
     def step(self) -> None:
         """One engine step: admissions, then one slab decode dispatch."""
         self.trace.step = self.step_count
@@ -707,62 +709,74 @@ class InferenceEngine:
             self.metrics.on_finish(r.id, step)
 
     def _start(self, r: Request) -> None:
-        slot = self.pool.alloc()
-        s0 = len(r.prompt)
-        n_img = self.model.cfg.n_img_tokens
-        # what is still owed: a failover-resumed request already generated
-        # part of its budget (now folded into the prompt)
-        budget = r.max_new_tokens - len(r.generated)
-        # paged admission: longest page-aligned cached prefix, then the
-        # slot's page-table row (shared prefix pages refcount-bumped, fresh
-        # private pages for suffix + generation + speculative headroom).
-        # `conv` flags a hit that ran through pages a finished request
-        # published from its GENERATED tokens — a chat resuming its own
-        # prior turn. PoolExhausted here propagates to step(), requeued.
-        matched, shared, conv = (0, (), False) if r.extras else \
-            self.backend.prefix_match(r.prompt)
-        # Page allocation is sized from the TRUE request footprint — prompt
-        # + owed budget + speculative headroom — never from the pow2
-        # prefill bucket. Bucket padding is a compile-shape policy only:
-        # padded-tail writes land past the allocated footprint, where the
-        # page table reads the reserved sink page (masked garbage), so a
-        # bigger bucket must never cost real pages.
-        try:
-            self.backend.alloc_slot_pages(
-                slot, n_img + s0 + budget + self.cfg.speculate,
-                shared)
-        except PoolExhausted:
-            self.pool.free(slot)
-            raise
-        sp = self._prefill_len(s0)
-        tokens = np.zeros((1, sp), np.int32)
-        tokens[0, :s0] = r.prompt
-        batch = {"tokens": jnp.asarray(tokens)}
-        if r.extras:
-            batch.update({k: jnp.asarray(v) for k, v in r.extras.items()})
+        with span("serve.admit"):
+            self.trace.admit_begin()
+            slot = self.pool.alloc()
+            s0 = len(r.prompt)
+            n_img = self.model.cfg.n_img_tokens
+            # what is still owed: a failover-resumed request already
+            # generated part of its budget (now folded into the prompt)
+            budget = r.max_new_tokens - len(r.generated)
+            # paged admission: longest page-aligned cached prefix, then the
+            # slot's page-table row (shared prefix pages refcount-bumped,
+            # fresh private pages for suffix + generation + speculative
+            # headroom). `conv` flags a hit that ran through pages a
+            # finished request published from its GENERATED tokens — a chat
+            # resuming its own prior turn. PoolExhausted here propagates to
+            # step(), requeued.
+            matched, shared, conv = (0, (), False) if r.extras else \
+                self.backend.prefix_match(r.prompt)
+            # Page allocation is sized from the TRUE request footprint —
+            # prompt + owed budget + speculative headroom — never from the
+            # pow2 prefill bucket. Bucket padding is a compile-shape policy
+            # only: padded-tail writes land past the allocated footprint,
+            # where the page table reads the reserved sink page (masked
+            # garbage), so a bigger bucket must never cost real pages.
+            try:
+                self.backend.alloc_slot_pages(
+                    slot, n_img + s0 + budget + self.cfg.speculate,
+                    shared)
+            except PoolExhausted:
+                self.pool.free(slot)
+                raise
+            sp = self._prefill_len(s0)
+            tokens = np.zeros((1, sp), np.int32)
+            tokens[0, :s0] = r.prompt
+            batch = {"tokens": jnp.asarray(tokens)}
+            if r.extras:
+                batch.update({k: jnp.asarray(v) for k, v in r.extras.items()})
+            self.trace.admit(r.id, slot, matched, s0)
+        with span("serve.prefill"):
+            if matched:
+                # prefix hit: only the unmatched suffix runs, right-padded
+                # into the same pow2 buckets as full prefills (real traffic
+                # produces arbitrary suffix lengths — one compile per length
+                # would be a compile-shape explosion). The logits column at
+                # the TRUE suffix end seeds sampling; the padded tail's
+                # writes land past the shared region — in the slot's
+                # private pages where the footprint still covers them, in
+                # the reserved sink page where it doesn't — masked garbage
+                # either way until decode overwrites the real positions.
+                # `batch` still carries the full padded prompt for a
+                # speculating backend's draft.
+                s_sfx = s0 - matched
+                sp_sfx = self._suffix_len(s_sfx, n_img + matched)
+                sfx = np.zeros((1, sp_sfx), np.int32)
+                sfx[0, :s_sfx] = r.prompt[matched:]
+                logits = self.backend.prefill_suffix(
+                    {"tokens": jnp.asarray(sfx)}, batch, slot,
+                    n_img + matched)
+                row = logits[:, s_sfx - 1]
+            else:
+                logits, caches = self.backend.prefill(batch, exact=sp == s0)
+                # (1, vocab) on device: the true prompt-end column
+                row = logits[:, -1] if sp == s0 \
+                    else logits[:, n_img + s0 - 1]
+                self.backend.write_slot(slot, caches)
         if matched:
-            # prefix hit: only the unmatched suffix runs, right-padded into
-            # the same pow2 buckets as full prefills (real traffic produces
-            # arbitrary suffix lengths — one compile per length would be a
-            # compile-shape explosion). The logits column at the TRUE
-            # suffix end seeds sampling; the padded tail's writes land past
-            # the shared region — in the slot's private pages where the
-            # footprint still covers them, in the reserved sink page where
-            # it doesn't — masked garbage either way until decode
-            # overwrites the real positions. `batch` still carries the
-            # full padded prompt for a speculating backend's draft.
-            s_sfx = s0 - matched
-            sp_sfx = self._suffix_len(s_sfx, n_img + matched)
-            sfx = np.zeros((1, sp_sfx), np.int32)
-            sfx[0, :s_sfx] = r.prompt[matched:]
-            logits = self.backend.prefill_suffix(
-                {"tokens": jnp.asarray(sfx)}, batch, slot, n_img + matched)
-            row = logits[:, s_sfx - 1]
+            self.trace.prefill(r.id, slot, s_sfx, sp_sfx, True)
         else:
-            logits, caches = self.backend.prefill(batch, exact=sp == s0)
-            # (1, vocab) on device: the true prompt-end column
-            row = logits[:, -1] if sp == s0 else logits[:, n_img + s0 - 1]
-            self.backend.write_slot(slot, caches)
+            self.trace.prefill(r.id, slot, s0, sp, False)
             if self._quality_every:
                 # every quality_every-th FULL-prefill admission (prefix-hit
                 # suffixes are skipped: their logits depend on page state
@@ -788,27 +802,23 @@ class InferenceEngine:
         r.tier = max(r.tier, self.backend.tier)
         self._slots[slot] = r
         self.metrics.on_start(r.id, self.step_count)
-        self.trace.admit(r.id, slot, matched, s0)
         self.trace.req_tier(r.id, self.backend.tier)
-        if matched:
-            self.trace.prefill(r.id, slot, s_sfx, sp_sfx, True)
-        else:
-            self.trace.prefill(r.id, slot, s0, sp, False)
-        if self.cfg.device_loop:
-            tok = self.backend.first_token(row, r.id, r.temperature)
-            self.metrics.on_host_sync("prefill")     # the one int32 pulled
-            self.trace.host_sync("prefill", 4)
-            eos = -1 if r.eos_id is None else int(r.eos_id)
-            rem = 0 if (r.eos_id is not None and tok == r.eos_id) \
-                else budget - 1
-            self.backend.install(slot, tok, r.index, r.temperature, eos, rem,
-                                 self._spec_limit(r))
-        else:
-            tok = self._sample_host(np.asarray(row[0]), r)
-            self.metrics.on_host_sync("prefill")
-            self.trace.host_sync("prefill", 4)
-            self._tokens[slot, 0] = tok
-            self._indices[slot] = r.index
+        with span("serve.first_token"):
+            if self.cfg.device_loop:
+                tok = self.backend.first_token(row, r.id, r.temperature)
+                self.metrics.on_host_sync("prefill")  # the one int32 pulled
+                self.trace.host_sync("prefill", 4)
+                eos = -1 if r.eos_id is None else int(r.eos_id)
+                rem = 0 if (r.eos_id is not None and tok == r.eos_id) \
+                    else budget - 1
+                self.backend.install(slot, tok, r.index, r.temperature, eos,
+                                     rem, self._spec_limit(r))
+            else:
+                tok = self._sample_host(np.asarray(row[0]), r)
+                self.metrics.on_host_sync("prefill")
+                self.trace.host_sync("prefill", 4)
+                self._tokens[slot, 0] = tok
+                self._indices[slot] = r.index
         self._emit(r, tok, self.step_count)  # may finish (max_new_tokens == 1)
 
     def _quality_probe(self, r: Request, batch, row, exact: bool,
@@ -842,8 +852,14 @@ class InferenceEngine:
         self.metrics.on_decode_step(n_active, self.cfg.n_slots,
                                     micro_steps=k)
         self.trace.dispatch_begin()
-        block = self.backend.decode_block()
+        with span("serve.decode"):
+            block = self.backend.decode_block()
         self.trace.decode_dispatch(k, n_active, self.cfg.n_slots)
+        return self._emit_block(block, k)
+
+    @spanned("serve.emit")
+    def _emit_block(self, block: np.ndarray, k: int) -> int:
+        """Host bookkeeping after a fused dispatch's one sync."""
         if self._gather_bytes:
             self.metrics.on_gather_avoided(self._gather_bytes)
             self.trace.gather_avoided(self._gather_bytes)
@@ -904,8 +920,15 @@ class InferenceEngine:
         self.metrics.on_decode_step(n_active, self.cfg.n_slots,
                                     micro_steps=(k + 1) + self._verify_steps)
         self.trace.dispatch_begin()
-        block, n_commit, n_accept = self.backend.spec_decode_block()
+        with span("serve.spec"):
+            block, n_commit, n_accept = self.backend.spec_decode_block()
         self.trace.spec_dispatch(k, n_active, self.cfg.n_slots)
+        return self._emit_spec(block, n_commit, n_accept, k)
+
+    @spanned("serve.emit")
+    def _emit_spec(self, block: np.ndarray, n_commit: np.ndarray,
+                   n_accept: np.ndarray, k: int) -> int:
+        """Host bookkeeping after a speculative dispatch's one sync."""
         if self._gather_bytes:
             self.metrics.on_gather_avoided(self._gather_bytes)
             self.trace.gather_avoided(self._gather_bytes)
@@ -968,8 +991,14 @@ class InferenceEngine:
         n_active = self.pool.n_active
         self.metrics.on_decode_step(n_active, self.cfg.n_slots)
         self.trace.dispatch_begin()
-        rows = self.backend.decode_host(self._tokens, self._indices)
+        with span("serve.decode"):
+            rows = self.backend.decode_host(self._tokens, self._indices)
         self.trace.decode_dispatch(1, n_active, self.cfg.n_slots)
+        return self._emit_host(rows)
+
+    @spanned("serve.emit")
+    def _emit_host(self, rows: np.ndarray) -> int:
+        """Host sampling and bookkeeping after a host-loop step."""
         # logits pull + token and index uploads: 3 crossings per step
         self.metrics.on_host_sync("decode", 3)
         self.trace.host_sync("decode", self._sync_bytes)

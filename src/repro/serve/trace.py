@@ -39,6 +39,19 @@ Exports:
     as complete ("X") events with their step-clock numbers in `args`, and
     an occupancy counter track.
 
+Profiler spans: `span(name)` is a `jax.profiler.TraceAnnotation`, so the
+engine's phases land in a profiler capture on the same clock as the device
+ops. The engine opens one span per phase (`SPANS`): `serve.step` around the
+whole step and, inside it, `serve.admit` (slot, prefix match and pages, up
+to the prefill dispatch), `serve.prefill` (the prefill or suffix prefill
+and the slab write), `serve.first_token` (sampling the first token, its
+sync and the device-state install), `serve.decode` / `serve.spec` (the
+fused dispatch and its one token sync) and `serve.emit` (the host
+bookkeeping after that sync). A span records only while a profiler session
+is active, so it needs no switch. The ring events that time a phase carry
+its span's name without the `serve.` prefix (`admit`, `prefill`,
+`first_token`, `decode`, `spec`).
+
 Profiler capture: `TraceConfig.profile_dir` brackets the first
 `profile_dispatches` traced decode dispatches with
 `jax.profiler.start_trace/stop_trace`, so the DEVICE-side timeline of the
@@ -57,10 +70,28 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import json
 import os
 import time
 from typing import Any, Dict, List, Optional, Sequence
+
+import jax
+
+# the engine's host spans, outermost first (see the module docstring)
+SPANS = ("serve.step", "serve.admit", "serve.prefill", "serve.first_token",
+         "serve.decode", "serve.spec", "serve.emit")
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """Host span `name` on the profiler's clock (one of `SPANS`)."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def spanned(name: str):
+    """Decorator form of `span`: every call of the function is span
+    `name`."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,6 +135,9 @@ class NullTracer:
         pass
 
     def reject(self, n_waiting):
+        pass
+
+    def admit_begin(self):
         pass
 
     def admit(self, rid, slot, matched, n_prompt):
@@ -209,6 +243,8 @@ class Tracer(NullTracer):
             maxlen=self.cfg.capacity)
         self.dropped = 0                   # events evicted by the ring
         self._t0d = 0.0                    # dispatch_begin timestamp
+        self._t0a = 0.0                    # admit_begin timestamp
+        self._t0p = 0.0                    # prefill start (admit's end)
         self._profiling = False
         self._profile_left = (self.cfg.profile_dispatches
                               if self.cfg.profile_dir else 0)
@@ -232,15 +268,26 @@ class Tracer(NullTracer):
         self._push({"ev": "reject", "step": self.step, "t": self._t(),
                     "n_waiting": n_waiting})
 
+    def admit_begin(self):
+        self._t0a = self._t()
+
     def admit(self, rid, slot, matched, n_prompt):
-        self._push({"ev": "admit", "step": self.step, "t": self._t(),
-                    "rid": rid, "slot": slot, "prefix_matched": matched,
-                    "prefix_skipped": matched, "n_prompt": n_prompt})
+        """Pushed once the slot and its pages are held, just before the
+        prefill dispatch; `t` is the admission's start (`admit_begin`)."""
+        self._t0p = self._t()
+        self._push({"ev": "admit", "step": self.step, "t": self._t0a,
+                    "dur": self._t0p - self._t0a, "rid": rid, "slot": slot,
+                    "prefix_matched": matched, "prefix_skipped": matched,
+                    "n_prompt": n_prompt})
 
     def prefill(self, rid, slot, n_tokens, n_padded, suffix):
-        self._push({"ev": "prefill", "step": self.step, "t": self._t(),
-                    "rid": rid, "slot": slot, "n_tokens": n_tokens,
-                    "n_padded": n_padded, "suffix": bool(suffix)})
+        """Pushed after the prefill and its slab write; `t` is the
+        prefill's start (the end of `admit`)."""
+        t = self._t()
+        self._push({"ev": "prefill", "step": self.step, "t": self._t0p,
+                    "dur": t - self._t0p, "rid": rid, "slot": slot,
+                    "n_tokens": n_tokens, "n_padded": n_padded,
+                    "suffix": bool(suffix)})
 
     def first_token(self, rid, slot, step):
         self._push({"ev": "first_token", "step": step, "t": self._t(),

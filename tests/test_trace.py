@@ -15,7 +15,7 @@ from repro.serve import (EngineConfig, InferenceEngine, ModelRegistry,
                          TelemetryExporter, TelemetryRegistry, TraceConfig,
                          Tracer, engine_sample, export_chrome, export_jsonl,
                          router_sample)
-from repro.serve.trace import NullTracer, chrome_events
+from repro.serve.trace import SPANS, NullTracer, chrome_events
 
 ARCH = "h2o-danube-1.8b"
 _REGISTRY = ModelRegistry()
@@ -58,6 +58,7 @@ def test_null_tracer_zero_alloc():
         t.first_token(1, 0, 3)
         t.finish(1, 0, 9, 6)
         t.submit(1, 2, 3)
+        t.admit_begin()
         t.admit(1, 0, 0, 4)
         t.prefill(1, 0, 4, 8, False)
         t.pool_wait()
@@ -138,6 +139,37 @@ def test_trace_counts_match_metrics_counters():
     for e in by_kind["decode"]:
         assert e["dur"] >= 0.0 and 0.0 < e["occupancy"] <= 1.0
         assert e["k"] == 2
+
+
+def test_admit_and_prefill_events_time_their_phases():
+    """`admit` is stamped where the admission starts and pushed before the
+    prefill dispatch; `prefill` starts where admission ends and carries its
+    duration, as `decode` does."""
+    eng = _engine(trace=TraceConfig())
+    eng.submit([1, 2, 3, 4, 5], 3)
+    eng.run()
+    evs = list(eng.trace.events)
+    kinds = [e["ev"] for e in evs]
+    i_admit, i_prefill = kinds.index("admit"), kinds.index("prefill")
+    i_first = kinds.index("first_token")
+    assert kinds.index("submit") < i_admit < i_prefill < i_first
+    submit, admit = evs[kinds.index("submit")], evs[i_admit]
+    prefill, first = evs[i_prefill], evs[i_first]
+    assert submit["t"] <= admit["t"]
+    assert admit["dur"] >= 0.0 and prefill["dur"] > 0.0
+    assert prefill["t"] == admit["t"] + admit["dur"]
+    assert prefill["t"] + prefill["dur"] <= first["t"]
+
+
+def test_ring_events_that_time_a_phase_are_named_as_its_span():
+    timed = {"admit", "prefill", "first_token", "decode", "spec"}
+    assert {f"serve.{k}" for k in timed} | {"serve.step", "serve.emit"} \
+        == set(SPANS)
+    eng = _engine(decode_chunk=2, trace=TraceConfig())
+    eng.submit([1, 2, 3], 4)
+    eng.run()
+    kinds = {e["ev"] for e in eng.trace.events}
+    assert timed - {"spec"} <= kinds
 
 
 def test_format_timeline_mentions_the_numbers():
