@@ -179,7 +179,7 @@ def make_decode_step(cfg: T.ModelConfig, backend: str = "ref", *,
     (lock-step batch), or an int32 (B,) vector of PER-SLOT counts — the
     continuous-batching slab decode, where each cache row advances on its
     own clock (serve.engine). One compiled step serves both regimes; the
-    vector form gathers/scatters per-slot cache offsets (models.attention).
+    vector form writes and reads per-slot cache offsets (models.attention).
 
     n_steps=K (device-resident loop, serve.engine):
         decode(params, caches, state) -> (tok_block, caches, state)
@@ -188,8 +188,11 @@ def make_decode_step(cfg: T.ModelConfig, backend: str = "ref", *,
     and per-slot EOS / length masking, so only the (K, B) int32 `tok_block`
     ever crosses to the host. `state` is the device-resident per-slot loop
     state (see `make_decode_state`); callers donate both `caches` and
-    `state`, so the KV slab updates in place instead of being copied per
-    token. The rng key is split once per MICRO-step (not per dispatch),
+    `state`. The layer-stacked cache is a carry of this micro-step scan and
+    of the forward's layer scan, never a scan xs/ys: each layer writes its
+    new token rows into the stacked leaves in place (T.forward), so the
+    compiled loop neither copies nor re-stacks the slab. The rng key is
+    split once per MICRO-step (not per dispatch),
     which makes sampled sequences identical for any K grouping of the same
     steps. Slots that finish mid-block (EOS or length) freeze their token /
     index / rng-free state; the host catches up from the synced block and
@@ -386,7 +389,7 @@ def make_paged_decode_step(cfg: T.ModelConfig, backend: str = "ref", *,
     page axis sits where the slot axis sat, so the treedef is unchanged)
     and the attention layers read/write them THROUGH the table
     (models.attention paged branches / kernels.ops.paged_attention): new-
-    token writes are in-place page-indexed scatters that preserve the
+    token writes are in-place page-indexed row writes that preserve the
     donated store's buffer aliasing, and no per-dispatch slab view ever
     materializes.
 

@@ -37,12 +37,15 @@ innermost contract name on its path (`bench/opscope.py` reads them):
   * `attn_core`   scores, mask, softmax, P.V, and the layout of K/V for
                   them: head split/merge, broadcast of K/V to the query
                   heads, the paged view of a cache, the flash kernel;
-  * `kv_write`    writing new K/V (or MLA latents) into a cache, and
-                  making a cache;
+  * `kv_write`    writing new K/V (or MLA latents) into a cache, in decode
+                  the token rows into the layer-stacked leaves, and making
+                  a cache;
   * `layer_scan`  the `lax.scan` over layers: its per-layer slices of the
-                  stacked weights and cache (xs) and their write-back (ys);
+                  stacked weights (xs), and in prefill of the cache it
+                  fills (xs) and returns (ys); decode carries the cache;
   * `decode_loop` the micro-step `lax.scan` of the fused decode dispatch:
-                  its carry copies;
+                  any copy of its carry (none since the cache is carried
+                  in place);
   * `gemm`        every Kratos projection and the LM head, with the
                   projection's name below it (`gemm/wq`, `gemm/head`);
   * `norm`        norms and the residual adds around them;

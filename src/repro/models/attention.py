@@ -19,8 +19,8 @@ Paged serving (serve.paging): the block-paged KV pool stores full-window and
 MLA caches page-major behind per-slot page tables. Decode consumes the
 table NATIVELY here: when the forward threads a `pages` operand
 ({'table': (B, pp) int32, 'size': page_size, 'len': cache_len}), the decode
-branches below write new K/V with in-place page-indexed scatters
-(position p lands in page table[b, p // P] at offset p % P) and read
+branches below write new K/V in place, one dynamic_update_slice per token
+(position p lands in page table[b, p // P] at offset p % P), and read
 through the table — the Pallas kernel path (kernels.ops.paged_attention)
 streams pages via its BlockSpec index map; the XLA/ref path takes a sliced
 contiguous view that is bit-identical to the slab rows on every valid
@@ -280,7 +280,7 @@ def _merge_heads(x):
 
 def gqa_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
               positions=None, cache=None, index=None,
-              kv_source=None, pages=None, probe=None,
+              kv_source=None, pages=None, probe=None, layer=None,
               ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Full-sequence (train/prefill) or single-step (decode) GQA attention.
 
@@ -293,6 +293,11 @@ def gqa_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
     leaves and ignore it.
     probe: serve.ledger probe (or None) — taps the merged attention output
     feeding the packed `wo` GEMM at trace time.
+    layer: decode over the layer scan's carry (models.transformer.forward):
+    the cache leaves are layer-STACKED, (n_layers, B, KV, S, dh) or
+    (n_layers, n_pages, KV, P, dh), and `layer` is this layer's row. The
+    new tokens' rows are written into the stack in place and the layer's
+    K/V read out of it; the returned cache is the whole stack.
     """
     b, s, d = x.shape
     h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -353,14 +358,16 @@ def gqa_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
         # NATIVE paged decode: cache leaves are page-major; write the new
         # tokens straight into their pages, read through the table.
         new_cache, o = _paged_gqa_decode(q, k, v, cfg, cache, index, pages,
-                                         positions, x.dtype, backend)
+                                         positions, x.dtype, backend, layer)
     else:
         # decode: write k/v at index (circular for windowed layers), attend
-        new_cache, kv_pos, valid = _decode_cache_write(cache, k, v, cfg, index)
+        new_cache, kv_pos, valid = _decode_cache_write(cache, k, v, cfg, index,
+                                                       layer)
         with scope("attn_core"):        # the cache's cast for the read too
             o = attention_positional(
-                q, new_cache["k"].astype(x.dtype),
-                new_cache["v"].astype(x.dtype), positions, kv_pos,
+                q, _layer_of(new_cache["k"], layer).astype(x.dtype),
+                _layer_of(new_cache["v"], layer).astype(x.dtype),
+                positions, kv_pos,
                 causal=cfg.causal, window=cfg.window, softcap=cfg.softcap,
                 extra_mask=valid, scale=cfg.scale)
     mo = _merge_heads(o)
@@ -397,8 +404,52 @@ def _prefill_cache(cache, k, v, cfg: AttnConfig):
     }
 
 
+def _layer_of(leaf, layer):
+    """The cache leaf of one layer: `leaf` itself, or row `layer` of a
+    layer-stacked leaf."""
+    if layer is None:
+        return leaf
+    return jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+
+
+def _put(leaf, update, start, layer):
+    """dynamic_update_slice of `update` at `start` into one layer's cache
+    leaf, or into row `layer` of a layer-stacked leaf."""
+    if layer is None:
+        return jax.lax.dynamic_update_slice(leaf, update, start)
+    return jax.lax.dynamic_update_slice(leaf, update[None],
+                                        (layer, *start))
+
+
+# The decode writes below put each slot's rows with their own
+# dynamic_update_slice, never one scatter over (layer, slot, position): for
+# such a scatter XLA re-lays the whole stacked leaf out and copies it back,
+# every layer and micro-step.
+
+def _write_rows(leaf, rows, at, layer=None):
+    """Slab write: rows (B, ..., s, d) into the cache leaf (B, ..., S, d)
+    at sequence offset `at` — a scalar for every row, or (B,) per slot."""
+    mid = (0,) * (rows.ndim - 3)
+    if jnp.ndim(at) == 0:
+        return _put(leaf, rows, (0, *mid, at, 0), layer)
+    for b in range(rows.shape[0]):
+        leaf = _put(leaf, rows[b:b + 1], (b, *mid, at[b], 0), layer)
+    return leaf
+
+
+def _write_pages(leaf, rows, page, off, layer=None):
+    """Paged write: token j of slot b of rows (B, ..., s, d) into page
+    page[b, j] of the store leaf (n_pages, ..., P, d), at row off[b, j]."""
+    mid = (0,) * (rows.ndim - 3)
+    for b in range(rows.shape[0]):
+        for j in range(rows.shape[-2]):
+            leaf = _put(leaf, rows[b:b + 1, ..., j:j + 1, :],
+                        (page[b, j], *mid, off[b, j], 0), layer)
+    return leaf
+
+
 @scoped("kv_write")
-def _decode_cache_write(cache, k, v, cfg: AttnConfig, index):
+def _decode_cache_write(cache, k, v, cfg: AttnConfig, index, layer=None):
     """Write s token(s) at `index`..; return (cache, kv_positions, valid).
 
     s > 1 is the contiguous block write: the speculative-verify block
@@ -416,10 +467,12 @@ def _decode_cache_write(cache, k, v, cfg: AttnConfig, index):
 
     index: scalar (lock-step batch, one shared position) or (B,) per-slot
     positions (continuous batching) — the vector form writes each batch row
-    at its own cache offset and returns per-row (B, size) positions/validity
-    for the per-slot attention mask.
+    at its own cache offset (one dynamic_update_slice per row) and returns
+    per-row (B, size) positions/validity for the per-slot attention mask.
+    layer: the leaves are layer-stacked and the rows land in layer `layer`
+    (see gqa_apply).
     """
-    size = cache["k"].shape[2]
+    size = cache["k"].shape[-2]
     last = index + (k.shape[2] - 1)      # last written position (s == 1: index)
     slot = (index % size) if cfg.window else index
     # the barrier stops XLA from sinking the f32->bf16 convert of the update
@@ -427,10 +480,10 @@ def _decode_cache_write(cache, k, v, cfg: AttnConfig, index):
     # into a full cache-stack copy per layer (4.6 GiB x 96 on nemotron).
     k, v = jax.lax.optimization_barrier(
         (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
+    ck = _write_rows(cache["k"], k, slot, layer)
+    cv = _write_rows(cache["v"], v, slot, layer)
     slots = jnp.arange(size)
     if jnp.ndim(index) == 0:
-        ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, slot, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, slot, 0))
         if cfg.window:
             # slot s holds the latest position p <= last with p % size == s
             kv_pos = last - ((last - slots) % size)
@@ -439,10 +492,6 @@ def _decode_cache_write(cache, k, v, cfg: AttnConfig, index):
             kv_pos = slots
             valid = slots <= last
     else:
-        write = jax.vmap(
-            lambda c, u, at: jax.lax.dynamic_update_slice(c, u, (0, at, 0)))
-        ck = write(cache["k"], k, slot)
-        cv = write(cache["v"], v, slot)
         if cfg.window:
             kv_pos = last[:, None] - ((last[:, None] - slots[None]) % size)
             valid = kv_pos >= 0
@@ -453,14 +502,16 @@ def _decode_cache_write(cache, k, v, cfg: AttnConfig, index):
 
 
 def _paged_gqa_decode(q, k, v, cfg: AttnConfig, cache, index, pages,
-                      positions, out_dtype, backend):
+                      positions, out_dtype, backend, layer=None):
     """Page-table-native decode for full-window GQA layers.
 
-    cache['k']/cache['v']: (n_pages, KV, P, dh) page-major store leaves.
-    Writes the s new tokens with one in-place page-indexed scatter per leaf
-    (the donated store updates in place — no slab view ever materializes),
-    then attends: the Pallas/interpret path streams pages through
-    kernels.ops.paged_attention's index map; the ref path takes the sliced
+    cache['k']/cache['v']: (n_pages, KV, P, dh) page-major store leaves, or
+    with `layer` the layer-stacked (n_layers, n_pages, KV, P, dh) ones.
+    Writes each of the s new tokens of every slot into its page in place
+    (one dynamic_update_slice per token: the donated store updates in place
+    and no slab view ever materializes), then attends: the Pallas/interpret
+    path streams pages through kernels.ops.paged_attention's index map;
+    the ref path takes the sliced
     contiguous view and runs the exact slab attention program (bit-identity
     with the slab decode branch by construction)."""
     b, s = q.shape[0], q.shape[2]
@@ -468,13 +519,11 @@ def _paged_gqa_decode(q, k, v, cfg: AttnConfig, cache, index, pages,
         page, off, last = _page_offsets(pages, index, b, s)
         k, v = jax.lax.optimization_barrier(
             (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
-        # advanced indices (B,s) at axes 0/2 with a sliced head axis
-        # between them move to the front: the update operand is
-        # (B, s, KV, dh).
-        ck = cache["k"].at[page, :, off, :].set(k.transpose(0, 2, 1, 3))
-        cv = cache["v"].at[page, :, off, :].set(v.transpose(0, 2, 1, 3))
+        ck = _write_pages(cache["k"], k, page, off, layer)
+        cv = _write_pages(cache["v"], v, page, off, layer)
     new_cache = {"k": ck, "v": cv}
     with scope("attn_core"):
+        ck, cv = _layer_of(ck, layer), _layer_of(cv, layer)
         if backend in ("pallas", "interpret") \
                 and q.shape[-1] == v.shape[-1]:
             o = ops.paged_attention(
@@ -541,8 +590,10 @@ def _mla_expand_kv(params, c_kv, cfg, spec, backend):
 
 def mla_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
               positions=None, cache=None, index=None,
-              kv_source=None, pages=None, probe=None,
+              kv_source=None, pages=None, probe=None, layer=None,
               ) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """MLA attention; the arguments are gqa_apply's (`layer`: decode writes
+    the latents into the layer-stacked leaves in place)."""
     b, s, d = x.shape
     h = cfg.n_heads
     if positions is None:
@@ -564,7 +615,7 @@ def mla_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
     with scope("kv_write"):
         if cache is not None and index is not None and pages is not None:
             # NATIVE paged decode: append latents straight into their pages
-            # (in-place page-indexed scatter), read the sliced table view —
+            # (in place, one row per token), read the sliced table view —
             # value-identical to the slab rows, so the expand below compiles
             # to the exact slab program. MLA stays on the XLA view path (the
             # Pallas paged kernel is GQA-shaped: dk != dv and the latent
@@ -574,13 +625,14 @@ def mla_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
                 (c_kv.astype(cache["c_kv"].dtype),
                  k_rope.astype(cache["k_rope"].dtype)))
             page, off, last = _page_offsets(pages, index, b, c_upd.shape[1])
-            ck = cache["c_kv"].at[page, off, :].set(c_upd)
-            cr = cache["k_rope"].at[page, :, off, :].set(
-                r_upd.transpose(0, 2, 1, 3))
+            ck = _write_pages(cache["c_kv"], c_upd, page, off, layer)
+            cr = _write_pages(cache["k_rope"], r_upd, page, off, layer)
             new_cache = {"c_kv": ck, "k_rope": cr}
             with scope("attn_core"):
-                c_all = _paged_leaf_view(ck, pages["table"], pages["len"])
-                kr_all = _paged_leaf_view(cr, pages["table"], pages["len"])
+                c_all = _paged_leaf_view(_layer_of(ck, layer), pages["table"],
+                                         pages["len"])
+                kr_all = _paged_leaf_view(_layer_of(cr, layer),
+                                          pages["table"], pages["len"])
             kv_pos = jnp.arange(pages["len"])
             valid = kv_pos[None] <= last[:, None]
         elif cache is not None and index is not None:
@@ -592,22 +644,16 @@ def mla_apply(params, x, cfg: AttnConfig, *, spec=kr.DENSE, backend="ref",
                  k_rope.astype(cache["k_rope"].dtype)))
             # s > 1: speculative block write
             last = index + (c_upd.shape[1] - 1)
+            ck = _write_rows(cache["c_kv"], c_upd, index, layer)
+            cr = _write_rows(cache["k_rope"], r_upd, index, layer)
+            kv_pos = jnp.arange(ck.shape[-2])
             if jnp.ndim(index) == 0:
-                ck = jax.lax.dynamic_update_slice(
-                    cache["c_kv"], c_upd, (0, index, 0))
-                cr = jax.lax.dynamic_update_slice(
-                    cache["k_rope"], r_upd, (0, 0, index, 0))
-                kv_pos = jnp.arange(ck.shape[1])
                 valid = kv_pos <= last
             else:                  # per-slot clocks (continuous batching)
-                ck = jax.vmap(lambda c, u, at: jax.lax.dynamic_update_slice(
-                    c, u, (at, 0)))(cache["c_kv"], c_upd, index)
-                cr = jax.vmap(lambda c, u, at: jax.lax.dynamic_update_slice(
-                    c, u, (0, at, 0)))(cache["k_rope"], r_upd, index)
-                kv_pos = jnp.arange(ck.shape[1])
                 valid = kv_pos[None] <= last[:, None]
             new_cache = {"c_kv": ck, "k_rope": cr}
-            c_all, kr_all = ck, cr
+            with scope("attn_core"):
+                c_all, kr_all = _layer_of(ck, layer), _layer_of(cr, layer)
         elif cache is not None:
             ck = jax.lax.dynamic_update_slice(
                 cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, 0, 0))

@@ -297,9 +297,33 @@ def _residual(x, h, rs):
     return x + h * rs
 
 
+def _on_layer(apply, cache, layer, in_place: bool):
+    """`apply(cache, **kw) -> (y, new_cache)` against one layer's cache.
+
+    layer None: `cache` is the layer's own. Otherwise `cache` is the
+    layer-stacked decode carry of the layer scan and `layer` this layer's
+    row: a mixer that writes its rows in place gets the stack and `layer`
+    and returns the stack; for any other (Mamba state, cross K/V) the row
+    is read out and its update written back into the carry."""
+    if layer is None or cache is None:
+        return apply(cache)
+    if in_place:
+        return apply(cache, layer=layer)
+    with scope("kv_write"):
+        row = jax.tree_util.tree_map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False),
+            cache)
+    y, new = apply(row)
+    with scope("kv_write"):
+        new = jax.tree_util.tree_map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                c, n.astype(c.dtype), layer, 0), cache, new)
+    return y, new
+
+
 def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
                  positions=None, cache=None, index=None, enc_out=None,
-                 cross_cache=None, pages=None, probe=None):
+                 cross_cache=None, pages=None, probe=None, layer=None):
     """One residual block. Returns (x, aux, new_cache, new_cross_cache).
 
     pages: page-table operand for native paged decode — consumed by the
@@ -308,6 +332,8 @@ def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
     probe: serve.ledger probe (or None) — taps the normalized mixer/FFN
     GEMM inputs (and, inside attention, the pre-wo merged heads) at trace
     time; the forward drains one summed row per layer.
+    layer: decode over layer-stacked caches (see `_on_layer`): `cache` and
+    `cross_cache` hold every scanned layer's leaves, `layer` is this one.
     """
     aux = jnp.zeros((), jnp.float32)
     rs = jnp.asarray(cfg.residual_scale, x.dtype)
@@ -320,15 +346,19 @@ def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
     if probe is not None:
         probe.tap(h, _probe_fanout(cfg, kind, "mixer"))
     new_cache = new_cross = None
-    if kind["mixer"] == "attn":
-        h, new_cache = A.attn_apply(
-            p["mixer"], h, attn_cfg_for(cfg, kind), spec=cfg.kratos,
-            backend=backend, positions=positions, cache=cache, index=index,
-            pages=pages, probe=probe)
+    if kind["mixer"] == "attn":         # GQA and MLA write rows in place
+        h, new_cache = _on_layer(
+            lambda c, **kw: A.attn_apply(
+                p["mixer"], h, attn_cfg_for(cfg, kind), spec=cfg.kratos,
+                backend=backend, positions=positions, cache=c, index=index,
+                pages=pages, probe=probe, **kw),
+            cache, layer, True)
     else:
-        h, new_cache = S.mamba_apply(
-            p["mixer"], h, mamba_cfg_for(cfg), spec=cfg.kratos,
-            backend=backend, cache=cache, index=index)
+        h, new_cache = _on_layer(
+            lambda c: S.mamba_apply(
+                p["mixer"], h, mamba_cfg_for(cfg), spec=cfg.kratos,
+                backend=backend, cache=c, index=index),
+            cache, layer, False)
     if cfg.sandwich_norm:
         h = _norm(cfg, p["post_norm"], h)
     x = _residual(x, h, rs)
@@ -336,9 +366,11 @@ def _layer_apply(p: Dict, x, cfg: ModelConfig, kind: Dict, *, backend="ref",
         h = L.shard(_norm(cfg, p["cross_norm"], x), "batch", None, "dm_in")
         ccfg = dataclasses.replace(attn_cfg_for(cfg, kind), cross=True,
                                    causal=False, use_rope=False)
-        h, new_cross = A.attn_apply(
-            p["cross"], h, ccfg, spec=cfg.kratos, backend=backend,
-            kv_source=enc_out, cache=cross_cache, index=index, probe=probe)
+        h, new_cross = _on_layer(
+            lambda c: A.attn_apply(
+                p["cross"], h, ccfg, spec=cfg.kratos, backend=backend,
+                kv_source=enc_out, cache=c, index=index, probe=probe),
+            cross_cache, layer, False)
         x = _residual(x, h, rs)
     if kind["ffn"] != "none":
         h = L.shard(_norm(cfg, p["ffn_norm"], x), "batch", None, "dm_in")
@@ -547,7 +579,12 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, *, backend="ref",
                 entry["cross"] = ncr
             new_caches["prelude"].append(entry)
 
-    # scanned periodic blocks
+    # scanned periodic blocks. Decode carries the stacked cache through the
+    # scan and each layer writes its new rows into it (`layer` = the
+    # layer's row); as scan xs/ys the whole stack would be sliced out and
+    # stacked back every layer and micro-step. Prefill and training make a
+    # cache rather than update one, and keep the xs/ys form.
+    decode = caches is not None and index is not None
     n_periods = (cfg.n_layers - cfg.prelude_layers) // cfg.scan_period
     slot_rows: List[jnp.ndarray] = []
     for slot in range(cfg.scan_period):
@@ -556,35 +593,43 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, *, backend="ref",
         c_stack = caches["blocks"][slot] if caches is not None else None
 
         def body(carry, xs, _kind=kind):
-            x, aux = carry
-            if caches is not None:
-                lp, cache_sl = xs
-                mc = cache_sl.get("mixer")
-                cc = cache_sl.get("cross")
+            x, aux, carried = carry
+            if decode:
+                (lp, layer), cache_sl = xs, carried
+            elif caches is not None:
+                (lp, cache_sl), layer = xs, None
             else:
-                lp, mc, cc = xs, None, None
+                lp, cache_sl, layer = xs, {}, None
             x, a, nm, ncr = _layer_apply(
                 lp, x, cfg, _kind, backend=backend, positions=positions,
-                cache=mc, index=index, enc_out=enc_out, cross_cache=cc,
-                pages=pages, probe=probe)
+                cache=cache_sl.get("mixer"), index=index, enc_out=enc_out,
+                cross_cache=cache_sl.get("cross"), pages=pages, probe=probe,
+                layer=layer)
             out = None
             if caches is not None:
                 out = {"mixer": nm}
                 if ncr is not None:
                     out["cross"] = ncr
+            if decode:
+                carried, out = out, None
             if probe is not None:
                 out = (out, probe.layer_row())   # row exits via scan ys
-            return (x, aux + a), out
+            return (x, aux + a, carried), out
 
-        xs = (stacked, c_stack) if caches is not None else stacked
+        if decode:
+            init, xs = (x, aux_total, c_stack), \
+                (stacked, jnp.arange(n_periods, dtype=jnp.int32))
+        else:
+            init = (x, aux_total, None)
+            xs = (stacked, c_stack) if caches is not None else stacked
         with scope("layer_scan"):
-            (x, aux_total), new_stack = jax.lax.scan(
-                _remat_wrap(cfg, body), (x, aux_total), xs)
+            (x, aux_total, carried), new_stack = jax.lax.scan(
+                _remat_wrap(cfg, body), init, xs)
         if probe is not None:
             new_stack, rows = new_stack          # (n_periods, width)
             slot_rows.append(rows)
         if caches is not None:
-            new_caches["blocks"][slot] = new_stack
+            new_caches["blocks"][slot] = carried if decode else new_stack
 
     with scope("norm"):
         if last_only:

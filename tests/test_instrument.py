@@ -81,12 +81,16 @@ PROGRAMS = [("h2o-danube-1.8b", KratosSpec(sparsity=0.5, bits=8, bk=8, bn=8),
 _OPS = re.compile(r"= \S+ (dot|custom-call|dynamic-update-slice|scatter)\(")
 
 
-def _lowered(arch, spec, kw):
-    """The engine's fused decode and its prefill, lowered (not compiled)."""
+def _backend(arch, spec, kw):
     reg = ModelRegistry()
     model = reg.load(arch, spec) if spec is not None else reg.load(arch)
-    b = InferenceEngine(model, EngineConfig(n_slots=2, max_len=32,
-                                            decode_chunk=2, **kw)).backend
+    return InferenceEngine(model, EngineConfig(n_slots=2, max_len=32,
+                                               decode_chunk=2, **kw)).backend
+
+
+def _lowered(arch, spec, kw, b=None):
+    """The engine's fused decode and its prefill, lowered (not compiled)."""
+    b = b or _backend(arch, spec, kw)
     if b.paged:
         dec = b._decode.lower(b.params, b.pool.store, b.pool.page_table,
                               b.state)
@@ -135,10 +139,11 @@ def _innermost(line: str) -> str:
 def test_slab_decode_ops_carry_their_own_scope():
     """Which scope, not only some: in the slab decode the K/V broadcast to
     the query heads and the score and P.V dots lie under `attn_core`, the
-    projections under `gemm`, the new token's K/V write under `kv_write`
-    and the write-back of the stacked cache under `layer_scan`. A change
-    that moves one of these ops to another scope moves `decode_attn_ms` or
-    `decode_cache_ms` without changing the work, so it has to show here."""
+    projections under `gemm`, and the new token's K/V rows, written per
+    slot into the layer-stacked cache, under `kv_write`; no op under
+    `layer_scan` writes a K/V leaf. A change that moves one of these ops
+    to another scope moves `decode_attn_ms` or `decode_cache_ms` without
+    changing the work, so it has to show here."""
     arch, spec, kw = PROGRAMS[0]
     cfg = ModelRegistry().load(arch).cfg
     kv, dh = cfg.n_kv_heads, cfg.head_dim
@@ -156,11 +161,57 @@ def test_slab_decode_ops_carry_their_own_scope():
     assert projections and {_innermost(d) for d in projections} == {"gemm"}
     attention = [_innermost(d) for d in dots if d not in projections]
     assert len(attention) >= 2 and set(attention) == {"attn_core"}
-    token_write = scopes(rf"= f32\[\d+,{kv},\d+,{dh}\]\S* scatter\(")
-    assert token_write and set(token_write) == {"kv_write"}
-    write_back = scopes(rf"= f32\[{cfg.n_layers},\d+,{kv},\d+,{dh}\]\S* "
-                        r"dynamic-update-slice\(")
-    assert write_back and set(write_back) == {"layer_scan"}
+    token_write = scopes(rf"= f32\[{cfg.n_layers},\d+,{kv},\d+,{dh}\]\S* "
+                         r"dynamic-update-slice\(")
+    assert len(token_write) >= 2 and set(token_write) == {"kv_write"}
+    kv_leaf_write = scopes(rf"= f32\[(\d+,)?\d+,{kv},\d+,{dh}\]\S* "
+                           r"(dynamic-update-slice|scatter)\(")
+    assert "layer_scan" not in kv_leaf_write
+
+
+_OP = re.compile(r"%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)")
+
+
+def _shape(dtype: str, dims: str, squeeze: bool = False) -> tuple:
+    """('f32', '1,2,8') -> ('f32', (1, 2, 8)), or with `squeeze` the
+    leading unit axes dropped: ('f32', (2, 8))."""
+    dims = [int(d) for d in dims.split(",") if d]
+    while squeeze and dims and dims[0] == 1:
+        dims.pop(0)
+    return dtype, tuple(dims)
+
+
+@pytest.mark.parametrize(
+    "arch,spec,kw", PROGRAMS[:2] + [("nemotron-4-340b", None,
+                                     {"page_size": 8})],
+    ids=["slab-packed", "paged", "paged-store"])
+def test_fused_decode_never_copies_the_kv_stack(arch, spec, kw):
+    """The compiled fused decode writes each new token row into the
+    layer-stacked K/V leaves (slab or page store) where they lie: no op
+    copies a whole stacked leaf (a loop carry's copy, a scan's ys buffer)
+    and no dynamic-update-slice writes a whole layer's slice back. The
+    danube programs' windowed leaves stay resident slabs even in the paged
+    engine; the nemotron one reads and writes true page-store leaves."""
+    b = _backend(arch, spec, kw)
+    tree = b.pool.layout.as_tree(b.pool.store) if b.paged else b.pool.caches
+    stacks = {(str(leaf.dtype).replace("float", "f"), tuple(leaf.shape))
+              for leaf in jax.tree_util.tree_leaves(tree["blocks"])}
+    layers = {_shape(dt, ",".join(map(str, shape[1:])), squeeze=True)
+              for dt, shape in stacks}
+    text = _lowered(arch, spec, kw, b)["decode"].compile().as_text()
+    ops = [m.groups() for m in _OP.finditer(text)]
+    shapes = {name: _shape(dt, dims, squeeze=True)
+              for name, dt, dims, _, _ in ops}
+    row_writes = 0
+    for name, dt, dims, op, args in ops:
+        result = _shape(dt, dims)
+        if op in ("copy", "copy-start"):
+            assert result not in stacks, (op, name, result)
+        elif op == "dynamic-update-slice":
+            update = re.findall(r"%([\w.\-]+)", args)[1]
+            assert shapes.get(update) not in layers, (name, shapes[update])
+            row_writes += result in stacks
+    assert row_writes, "no token row written into a stacked leaf"
 
 
 def _stripped(text: str) -> str:
