@@ -15,7 +15,7 @@ from pathlib import Path
 from typing import Dict, List
 
 from bench import check, loop, system, traffic, xplane
-from bench.context import DECODE_PROGRAM, Ctx, percentile
+from bench.context import ABSENT, DECODE_PROGRAM, Ctx, percentile
 from bench.peaks import peaks_for
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -26,6 +26,25 @@ TRACE_S = 20.0          # the traced run profiles the window's last seconds
 
 def load_metric(name: str):
     return importlib.import_module(f"bench.metrics.{name}")
+
+
+def read_per_layer(ctx: Ctx, per_layer: List[Dict]) -> Dict[str, Dict]:
+    """{name: {'value', 'unit'}} of the per-layer metrics a traced run
+    reads. A metric whose reader finds nothing (None) is left out, except
+    in a device capture, where the run fails: a program or kernel was not
+    matched. One whose reader answers ABSENT, a program older than what
+    it reads, is left out."""
+    metrics = {}
+    for spec in per_layer:
+        value = load_metric(spec["name"]).read(ctx)
+        if value is ABSENT:
+            continue
+        if value is None and ctx.plane:
+            raise RuntimeError(f"{spec['name']}: nothing to read in the "
+                               f"capture of {ctx.plane}")
+        if value is not None:
+            metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
+    return metrics
 
 
 def end_to_end(window: loop.Window, seconds: float) -> Dict[str, float]:
@@ -121,15 +140,7 @@ def run(config: Dict, mix: Dict, seed: int, seconds: float, trace: bool,
         cap = xplane.load(xplane.find_xplane(str(TRACE_DIR)))
         plane = sorted(cap.ops)[0] if cap.ops else ""
         ctx = Ctx(config, mix, window, peaks, cap, plane)
-        for spec in per_layer:
-            value = load_metric(spec["name"]).read(ctx)
-            if value is None and plane:
-                # a device capture in which a metric this cell reports
-                # finds nothing: a program or kernel was not matched
-                raise RuntimeError(f"{spec['name']}: nothing to read in the "
-                                   f"capture of {plane}")
-            if value is not None:
-                metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
+        metrics.update(read_per_layer(ctx, per_layer))
         if plane:
             notes.update(
                 traced_steps=len(ctx.traced_steps()),

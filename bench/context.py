@@ -1,8 +1,9 @@
 """What a per-layer metric's reader is given: one run's records and capture.
 
 A reader (`metrics/<name>.py`) defines `read(ctx)` and returns a number,
-or None when the run holds nothing for it to read; the harness then
-leaves the metric out of the line.
+None when the run holds nothing for it to read, or ABSENT when the program
+under test predates what the metric reads; the harness then leaves the
+metric out of the line (and fails a traced run on the chip for a None).
 """
 
 from __future__ import annotations
@@ -12,11 +13,23 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import xplane
+from bench import system, xplane
 from bench.loop import StepRec, Window
 
 PREFILL_PROGRAM = "jit_prefill"
 DECODE_PROGRAM = "jit_decode"
+
+
+class _Absent:
+    """A reader's answer for a program older than what its metric reads,
+    such as a program that names no contract scope: the metric is left
+    out of the line."""
+
+    def __repr__(self) -> str:
+        return "ABSENT"
+
+
+ABSENT = _Absent()
 
 
 @dataclasses.dataclass
@@ -39,6 +52,11 @@ class Ctx:
     @property
     def engine(self) -> Dict:
         return self.config["engine"]
+
+    @property
+    def adapter(self):
+        """The configuration's architecture adapter (`system.adapter`)."""
+        return system.adapter(self.config)
 
     def step_spans(self) -> List[xplane.Event]:
         """The `engine.step` host spans of the capture: one per traced

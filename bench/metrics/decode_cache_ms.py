@@ -6,8 +6,8 @@ scan's own per-layer slices of the stacked cache and weights, and their
 write-back) or `decode_loop` (the micro-step loop's carry copies of the
 slab). The weights' slices are a small part of `layer_scan` (PERF.md,
 section 3), but a change to the stacked weights' layout moves this too.
-On a program whose ops carry no contract scope this reads 0, as
-`decode_attn_ms` does, which measures no cache movement.
+On a program whose ops carry no contract scope this reads ABSENT, as
+`decode_attn_ms` does.
 Layer: KV cache movement (`models/attention.py`, `models/transformer.py`,
 `distributed/steps.py`).
 """
@@ -17,6 +17,6 @@ from bench import opscope
 
 def read(ctx):
     split = opscope.decode_split_ms(ctx)
-    if split is None:
-        return None
+    if not isinstance(split, dict):
+        return split
     return split["kv_write"] + split["layer_scan"] + split["decode_loop"]

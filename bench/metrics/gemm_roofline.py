@@ -2,9 +2,10 @@
 
 Numerator: the least time the chip needs for the work every packed
 projection of each decode micro-step requires at the configuration's
-Kratos point (`bench/workcount.py`, peaks from `bench/peaks.py`), over
-`n_slots` rows, times the micro-steps traced. Denominator: the device
-time of the GEMM kernel runs inside those decode programs. The trace
+Kratos point (`bench/workcount.py`, the projections from the
+architecture's adapter, peaks from `bench/peaks.py`), over `n_slots` rows,
+times the micro-steps traced. Denominator: the device time of the GEMM
+kernel runs inside those decode programs. The trace
 names a Pallas kernel's op by its HLO text only, so a configuration
 states how its GEMM kernel's op reads there (`gemm_ops`, a regular
 expression over that text: the Mosaic call and its operands). Layer:
@@ -26,5 +27,5 @@ def read(ctx):
         return None
     k = ctx.engine["decode_chunk"]
     least = len(runs) * k * workcount.decode_gemm_least_s(
-        ctx.model, ctx.kratos, ctx.engine["n_slots"], ctx.peaks)
+        ctx.adapter, ctx.model, ctx.kratos, ctx.engine["n_slots"], ctx.peaks)
     return 100.0 * least / (sum(e - s for _, s, e in ops) / 1e9)

@@ -25,7 +25,7 @@ import dataclasses
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from bench import xplane
-from bench.context import DECODE_PROGRAM
+from bench.context import ABSENT, DECODE_PROGRAM
 
 SCOPES = ("attn_core", "kv_write", "layer_scan", "decode_loop", "gemm",
           "norm", "rope", "mlp_act", "embed", "sample")
@@ -258,11 +258,12 @@ def split_ns(ops: Sequence[Op]) -> Dict[str, int]:
     return out
 
 
-def decode_split_ms(ctx) -> Optional[Dict[str, float]]:
+def decode_split_ms(ctx):
     """Milliseconds per decode micro-step of each scope, over the ops of the
     decode programs that ran within traced engine steps (`Ctx.programs`):
     device time / (runs x `decode_chunk`). None without a device capture
-    or decode runs."""
+    or decode runs; ABSENT when no op of those runs names a contract scope,
+    as on a program older than the scopes."""
     if not ctx.plane:
         return None
     scoped = for_ctx(ctx)
@@ -273,8 +274,13 @@ def decode_split_ms(ctx) -> Optional[Dict[str, float]]:
     if "split" not in memo:
         per = len(runs) * ctx.engine["decode_chunk"]
         ops = xplane.inside(scoped.ops.get(ctx.plane, []), runs)
-        memo["split"] = {k: v / 1e6 / per
-                         for k, v in split_ns(ops).items()} if ops else None
+        if not ops:
+            memo["split"] = None
+        elif not any(scope_of(op[3]) for op in ops):
+            memo["split"] = ABSENT
+        else:
+            memo["split"] = {k: v / 1e6 / per
+                             for k, v in split_ns(ops).items()}
     return memo["split"]
 
 

@@ -33,6 +33,15 @@ def reports(metric: dict, cell: str, e2e_of_cell: set) -> bool:
     return "moves" not in metric or metric["moves"] in e2e_of_cell
 
 
+def load_config(name: str, root: Path = ROOT) -> dict:
+    """A configuration by name: the file `BENCHMARK.json` gives it, or
+    `bench/configs/<name>.json` for one that no cell uses yet."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    path = files.get(name, f"bench/configs/{name}.json")
+    return json.loads((root / path).read_text())
+
+
 def load_cell(name: str, root: Path = ROOT):
     """(cell, configuration, mix, end-to-end specs, per-layer specs)."""
     from bench import traffic
@@ -41,8 +50,7 @@ def load_cell(name: str, root: Path = ROOT):
     if name not in cells:
         raise SystemExit(f"no workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
-    files = {c["name"]: c["file"] for c in bench["configs"]}
-    config = json.loads((root / files[cell["config"]]).read_text())
+    config = load_config(cell["config"], root)
     mix = traffic.load_mix(cell["traffic"])
     e2e = [m for m in bench["end_to_end"] if reports(m, name, set())]
     names = {m["name"] for m in e2e}

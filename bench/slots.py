@@ -2,6 +2,9 @@
 
     python3 bench/slots.py --config danube-1.8b-s50w8 --traffic chat-0.68rps --slots 20,24,28
 
+The configuration is found by name (`run.load_config`), so one that no
+cell uses yet, such as a new cut, can be sized before its cell exists.
+
 One process makes and packs the weights once, then, for each slot count in
 ascending order, builds the engine, warms up the traffic's longest prompt
 and the fused decode, and prints the device's bytes in use and its peak.
@@ -30,9 +33,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     import jax
     from bench import cell, check, system, traffic
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    files = {c["name"]: c["file"] for c in bench["configs"]}
-    config = json.loads((ROOT / files[args.config]).read_text())
+    from bench.run import load_config
+    config = load_config(args.config)
     mix = traffic.load_mix(args.traffic)
     cell._configure_cache()
     dev = jax.devices()[0]

@@ -1,6 +1,9 @@
 """Find an open-loop cell's knee: the same mix at several fixed rates.
 
     python3 bench/sweep.py --workload <cell> --rates 4,6,8 --seconds 30 --seed 5
+    python3 bench/sweep.py --config <config> --traffic <mix> --rates 4,6,8 --seconds 30
+
+The second form sweeps a configuration and a mix that no cell names yet.
 
 One process builds the cell's engine once and runs one window per rate,
 in ascending order, draining between them, and stops after the first rate
@@ -30,15 +33,22 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--workload", required=True)
+    ap.add_argument("--workload", help="a cell of BENCHMARK.json")
+    ap.add_argument("--config", help="with --traffic, in place of a cell")
+    ap.add_argument("--traffic")
     ap.add_argument("--rates", required=True, help="comma-separated req/s")
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--seed", type=int, default=5)
     args = ap.parse_args(argv)
     import numpy as np
     from bench import cell, check, loop, system, traffic
-    from bench.run import load_cell
-    _, config, mix, _, _ = load_cell(args.workload)
+    from bench.run import load_cell, load_config
+    if args.workload:
+        _, config, mix, _, _ = load_cell(args.workload)
+    elif args.config and args.traffic:
+        config, mix = load_config(args.config), traffic.load_mix(args.traffic)
+    else:
+        raise SystemExit("name a --workload, or a --config and a --traffic")
     if mix["loop"] != "open":
         raise SystemExit("a sweep needs an open-loop mix")
     cell._configure_cache()
@@ -64,7 +74,8 @@ def main(argv=None) -> int:
         if w.waiting_at_close > eng["n_slots"] // 4 or row["failed"]:
             break
         knee = rate
-    print(json.dumps({"workload": args.workload, "knee_rps": knee}))
+    print(json.dumps({"workload": args.workload or
+                      f"{args.config}.{args.traffic}", "knee_rps": knee}))
     return 0
 
 

@@ -1,35 +1,37 @@
 """The benchmark's one adapter to the system under test.
 
-Everything the harness calls in the program goes through here: building
-the served model from the benchmark's own weights, the engine, the warm-up
-of exactly the programs a cell's traffic reaches, and host spans around
-the engine's calls into its layers. The program gets the weights; it never
-hands anything back to the reference.
+Everything the harness calls in the program goes through here: the
+program's model configuration built from the benchmark's file, the served
+model built from the benchmark's own weights, the engine, the warm-up of
+exactly the programs a cell's traffic reaches, and host spans around the
+engine's calls into its layers. The program gets the weights; it never
+hands anything back to the reference. What depends on the architecture,
+the parameter tree and the work one decode step applies, lives in
+`bench/adapters/<reference>.py`, found by the configuration's `reference`.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import importlib
+from pathlib import Path
 from typing import Dict, Iterable
 
 import jax
 
+ADAPTERS = Path(__file__).resolve().parent / "adapters"
 
-def program_tree(w: Dict) -> Dict:
-    """The program's parameter tree (one scanned block slot, no prelude)
-    over the reference's weight dict; shares every buffer."""
-    lw = w["layers"]
-    return {
-        "embed": {"emb": w["embed"]},
-        "final_norm": {"scale": w["final_norm"]},
-        "head": {"w": w["head"]},
-        "prelude": [],
-        "blocks": [{
-            "pre_norm": {"scale": lw["attn_norm"]},
-            "mixer": {k: {"w": lw[k]} for k in ("wq", "wk", "wv", "wo")},
-            "ffn_norm": {"scale": lw["mlp_norm"]},
-            "ffn": {k: {"w": lw[k]} for k in ("w_gate", "w_up", "w_down")},
-        }],
-    }
+
+def adapter(config: Dict):
+    """The module that maps the configuration's reference onto the
+    program: `bench/adapters/<reference>.py`, with `program_tree`,
+    `decode_projections` and `attention_flops`."""
+    name = config["reference"]
+    if not (ADAPTERS / f"{name}.py").is_file():
+        raise FileNotFoundError(
+            f"{config['name']}: reference {name!r} has no adapter "
+            f"bench/adapters/{name}.py")
+    return importlib.import_module(f"bench.adapters.{name}")
 
 
 def kratos_spec(kratos: Dict):
@@ -37,25 +39,44 @@ def kratos_spec(kratos: Dict):
     return KratosSpec(**kratos) if kratos else DENSE
 
 
-def _smoke(config: Dict) -> bool:
-    """Which of the program's configurations of `arch` the file states:
-    the published one (False) or its small CPU one (True). Raises when
-    the file's sizes match neither: the file states what is run."""
+def program_config(config: Dict):
+    """The program's ModelConfig that the file states: the published
+    configuration of `arch` with the file's `model` keys as overrides.
+    Every key whose value differs from the published one is a cut and must
+    be named in the file's `reduced` (missing reads as []). Raises, naming
+    the keys, for a key that ModelConfig lacks and for a change that
+    `reduced` does not name."""
     from repro import configs as C
-    for smoke, get in ((False, C.get_config), (True, C.get_smoke)):
-        cfg = get(config["arch"])
-        if all(getattr(cfg, k) == v for k, v in config["model"].items()):
-            return smoke
-    raise ValueError(f"{config['name']}: the model sizes match neither of "
-                     f"the program's configurations of {config['arch']!r}")
+    published = C.get_config(config["arch"])
+    model = config["model"]
+    unknown = sorted(set(model) - {f.name for f in
+                                   dataclasses.fields(published)})
+    if unknown:
+        raise ValueError(f"{config['name']}: {', '.join(unknown)} not keys "
+                         f"of the program's ModelConfig")
+    unlisted = [f"{k} ({getattr(published, k)!r} -> {v!r})"
+                for k, v in model.items() if getattr(published, k) != v
+                and k not in config.get("reduced", [])]
+    if unlisted:
+        raise ValueError(f"{config['name']}: changes {', '.join(unlisted)} "
+                         f"from the published {config['arch']} without "
+                         f"naming them in `reduced`")
+    return C.get_config(config["arch"], **model)
 
 
 def load_model(config: Dict, w: Dict):
-    """Pack the weights at the configuration's Kratos point."""
+    """Pack the weights at the configuration's Kratos point and serve them
+    under the configuration's own ModelConfig (`program_config`)."""
     from repro.serve.registry import ModelRegistry
-    return ModelRegistry().load(
-        config["arch"], kratos_spec(config["kratos"]),
-        params=program_tree(w), smoke=_smoke(config))
+    cfg = program_config(config)
+    tree = adapter(config).program_tree(w, config["model"])
+    model = ModelRegistry().load(
+        config["arch"], kratos_spec(config["kratos"]), params=tree,
+        smoke=False)
+    # given the parameters, the registry uses its published config of
+    # `arch` for nothing but the artifact's `cfg`, which this replaces
+    return dataclasses.replace(
+        model, cfg=dataclasses.replace(cfg, kratos=model.spec))
 
 
 def make_engine(config: Dict, model):

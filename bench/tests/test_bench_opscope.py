@@ -14,7 +14,7 @@ import pytest
 
 from tiny_cell import ROOT
 from bench import opscope, xplane
-from bench.context import DECODE_PROGRAM, Ctx
+from bench.context import ABSENT, DECODE_PROGRAM, Ctx
 
 DATA = Path(__file__).resolve().parent / "data"
 PLANE = "/device:TPU:0"
@@ -153,24 +153,50 @@ def _read(recorded, monkeypatch):
 
 def test_decode_readers_cover_the_decode_programs(recorded, monkeypatch):
     name, ctx, values = _read(recorded, monkeypatch)
-    split = opscope.decode_split_ms(ctx)
     runs = ctx.programs(DECODE_PROGRAM)
     per = len(runs) * CONFIG["engine"]["decode_chunk"]
     program_ms = sum(e - s for _, s, e in runs) / 1e6 / per
+    ops = xplane.inside(opscope.for_ctx(ctx).ops[PLANE], runs)
+    split = {k: v / 1e6 / per for k, v in opscope.split_ns(ops).items()}
     # every non-container op of the decode runs falls in one bucket, and
     # those ops fill the programs' device time to within 1%
     assert sum(split.values()) == pytest.approx(program_ms, rel=0.01)
-    assert all(v is not None and v >= 0.0 for v in values.values())
     assert values["step_idle_ms"] > 0.0
     if name == "s50w8":
-        # a program that names no scope: all of its time reads unscoped
-        assert values["decode_attn_ms"] == values["decode_cache_ms"] == 0.0
-        assert values["decode_unscoped_ms"] == sum(split.values())
+        # a program that names no scope: all of its time lies under none,
+        # and the scope readers have nothing to read
+        assert split[""] == sum(split.values())
+        assert opscope.decode_split_ms(ctx) is ABSENT
+        assert all(values[m] is ABSENT for m in READERS[:3])
     else:
+        assert opscope.decode_split_ms(ctx) == split
+        assert all(v >= 0.0 for v in values.values())
         assert values["decode_attn_ms"] > 0.0
         assert values["decode_cache_ms"] > 0.0
         assert split["gemm"] > 0.0
         assert values["decode_unscoped_ms"] < 0.2 * sum(split.values())
+
+
+def test_a_capture_without_scopes_leaves_the_scope_metrics_out(
+        scoped_recorded, monkeypatch):
+    """The scoped capture with every op's name stack stripped, as a
+    program older than the scopes records it: the three scope readers
+    answer ABSENT and the run's line leaves them out, while the metrics
+    that read no scope read as they do on the capture itself."""
+    from bench import cell
+    _, ctx, values = _read(scoped_recorded, monkeypatch)
+    specs = [{"name": m, "unit": "ms"} for m in READERS + ("decode_step_ms",)]
+    full = cell.read_per_layer(ctx, specs)
+    assert {k: v["value"] for k, v in full.items()} == {
+        **values, "decode_step_ms": full["decode_step_ms"]["value"]}
+    scoped = opscope.for_ctx(ctx)
+    stripped = Ctx(CONFIG, {}, None, {}, ctx.capture, PLANE)
+    vars(stripped)["_opscope"] = {"scoped": opscope.Scoped(
+        {p: [op[:3] + ("",) for op in ops] for p, ops in scoped.ops.items()},
+        scoped.spans)}
+    assert opscope.decode_split_ms(stripped) is ABSENT
+    assert cell.read_per_layer(stripped, specs) == {
+        k: full[k] for k in ("step_idle_ms", "decode_step_ms")}
 
 
 def test_scoped_capture_names_the_gemm_kernels_and_the_steps(

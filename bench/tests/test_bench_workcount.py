@@ -5,6 +5,7 @@ import pytest
 
 from tiny_cell import ROOT  # noqa: F401
 from bench import workcount
+from bench.adapters import gqa_decoder
 from bench.peaks import PEAKS, peaks_for
 
 S50W8 = {"sparsity": 0.5, "bits": 8, "bk": 128, "bn": 128}
@@ -44,6 +45,6 @@ def test_model_flops_per_token():
     m = {"n_layers": 1, "d_model": 2560, "n_heads": 32, "n_kv_heads": 8,
          "head_dim": 80, "d_ff": 6912, "vocab": 32000}
     per_layer = 2560 * 2560 * 2 + 2560 * 640 * 2 + 3 * 2560 * 6912
-    assert workcount.model_flops_per_token(m, {}) == \
+    assert workcount.model_flops_per_token(gqa_decoder, m, {}) == \
         2 * (per_layer + 2560 * 32000)
-    assert workcount.attention_flops(m, 1000) == 4 * 32 * 80 * 1000
+    assert gqa_decoder.attention_flops(m, 1000) == 4 * 32 * 80 * 1000

@@ -1,7 +1,8 @@
 """A cell cut to CPU size for the benchmark's own tests.
 
 The same harness, generator, engine settings and reference as a real
-cell, on the architecture's small configuration (`smoke=True`),
+cell, with the model's sizes cut to `TINY_MODEL` (each key it changes
+listed in `reduced`, as a cut configuration file lists its own),
 `backend="ref"`, four slots and short requests.
 """
 
@@ -37,9 +38,13 @@ def tiny_files(config_name: str, mix_name: str, backend: str = "ref"):
     return cut(config, traffic.load_mix(mix_name), backend)
 
 
-def cut(config, mix, backend: str = "ref"):
-    """A configuration and a mix cut to CPU size."""
-    config = dict(config, model=dict(TINY_MODEL),
+def cut(config, mix, backend: str = "ref", model=None):
+    """A configuration and a mix cut to CPU size: the model's sizes to
+    `model` (TINY_MODEL by default)."""
+    model = dict(model or TINY_MODEL)
+    cut_keys = {k for k, v in model.items() if config["model"].get(k) != v}
+    config = dict(config, model=model,
+                  reduced=sorted(cut_keys | set(config.get("reduced", []))),
                   engine=dict(config["engine"], backend=backend, n_slots=4,
                               max_len=64))
     # float32 on the CPU against the float32 reference: the served tokens

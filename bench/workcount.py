@@ -5,7 +5,10 @@ it: a projection of (n_in, n_out) applied to m rows needs 2 * m * nnz
 operations, where nnz is its stored nonzero weights, and reads those
 weights at `bits` (float32 where the spec keeps floats), their per-column
 scales, the kept block indices, the m * n_in activations in and writes the
-m * n_out activations out (float32).
+m * n_out activations out (float32). Which projections a decode step
+applies, on how many rows and how often, is the architecture's: its
+adapter (`bench/adapters/<reference>.py`, `system.adapter`) lists them in
+`decode_projections(model, m)`.
 """
 
 from __future__ import annotations
@@ -43,43 +46,23 @@ def least_time_s(work: Dict[str, float], peaks: Dict) -> float:
                work["bytes"] / peaks["hbm_bytes"])
 
 
-def layer_projections(model: Dict) -> Dict[str, tuple]:
-    d, dh = model["d_model"], model["head_dim"]
-    q, kv = model["n_heads"] * dh, model["n_kv_heads"] * dh
-    return {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-            "w_gate": (d, model["d_ff"]), "w_up": (d, model["d_ff"]),
-            "w_down": (model["d_ff"], d)}
-
-
-def decode_gemms(model: Dict, kratos: Dict, m: int) -> Dict[str, float]:
-    """Summed work of every packed projection of one decode micro-step
-    over m slot rows."""
-    tot = {"flops": 0.0, "bytes": 0.0, "nnz": 0.0}
-    for n_in, n_out in layer_projections(model).values():
-        w = projection(n_in, n_out, m, kratos)
-        for k in tot:
-            tot[k] += w[k] * model["n_layers"]
-    return tot
-
-
-def decode_gemm_least_s(model: Dict, kratos: Dict, m: int, peaks: Dict
-                        ) -> float:
+def decode_gemm_least_s(adapter, model: Dict, kratos: Dict, m: int,
+                        peaks: Dict) -> float:
     """Least time of the packed projections of one decode micro-step:
-    each projection's own roofline bound, summed."""
-    return model["n_layers"] * sum(
-        least_time_s(projection(n_in, n_out, m, kratos), peaks)
-        for n_in, n_out in layer_projections(model).values())
+    each projection's own roofline bound times its applications, summed.
+    Bounds of projections applied equally often are summed first."""
+    least: Dict[int, list] = {}
+    for n_in, n_out, rows, times in adapter.decode_projections(model, m):
+        least.setdefault(times, []).append(
+            least_time_s(projection(n_in, n_out, rows, kratos), peaks))
+    return sum(times * sum(t) for times, t in least.items())
 
 
-def model_flops_per_token(model: Dict, kratos: Dict) -> float:
-    """2 x stored nonzero weights a token multiplies: every projection at
-    its spec plus the float output head (the embedding is a gather)."""
-    nnz = decode_gemms(model, kratos, 1)["nnz"]
-    return 2.0 * (nnz + model["d_model"] * model["vocab"])
-
-
-def attention_flops(model: Dict, context: int) -> float:
-    """Scores and weighted sum of one token over `context` cached
-    positions, over all layers."""
-    return 4.0 * model["n_heads"] * model["head_dim"] * context \
-        * model["n_layers"]
+def model_flops_per_token(adapter, model: Dict, kratos: Dict) -> float:
+    """Operations of one token: the packed projections of a decode
+    micro-step on one row, at their spec, plus the float output head (the
+    embedding is a gather)."""
+    return sum(projection(n_in, n_out, rows, kratos)["flops"] * times
+               for n_in, n_out, rows, times
+               in adapter.decode_projections(model, 1)) \
+        + 2.0 * model["d_model"] * model["vocab"]
